@@ -1,15 +1,13 @@
 """End-to-end driver: distributed COCO-EF training of a transformer LM with
-checkpoint/restart, on whatever devices exist (CPU: set device count below).
+checkpoint/restart, on whatever devices exist.
 
 Demonstrates the full production path: mesh -> sharding rules -> stage-1
 coded gradients -> stage-2 wire-compressed aggregation -> server update ->
-checkpoint -> crash-resume.
+checkpoint -> crash-resume.  The mesh is (data=n, model=1) over the n
+devices JAX finds; on the CPU that is 8 fake devices.
 
   PYTHONPATH=src python examples/train_e2e.py [--steps 60]
 """
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import argparse
 import dataclasses
 import json
@@ -18,6 +16,9 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# 8 fake devices where JAX runs on the CPU; no other platform reads this
+jax.config.update("jax_num_cpu_devices", 8)
 
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import REGISTRY
@@ -166,10 +167,14 @@ def main():
                     help="where --metrics writes metrics.jsonl + trace.json")
     args = ap.parse_args()
 
-    from repro.compat import make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_host_mesh
+    enable_compile_cache()
+    mesh = make_host_mesh()
     shape = ShapeCfg("train", seq_len=64, global_batch=16)
     spec = REGISTRY[args.arch]
+    n_code = int(np.prod([mesh.shape[a] for a in spec.coding.coding_axes
+                          if a in mesh.shape]))
     coding_over = dict(group_size=32, block_size=64, k_per_block=8)
     if args.straggler_p is not None:
         coding_over["straggler_p"] = args.straggler_p
@@ -180,7 +185,6 @@ def main():
     if args.straggler == "trace" and trace_path is None:
         # synthesize a bursty incident trace for the demo and replay it
         from repro.sim import MarkovBursty, TraceReplay
-        n_code = 4    # pod x data of the mesh below
         p = spec.coding.straggler_p
         if args.straggler_p is None and p == 0:
             p = 0.2   # demo default; an explicit --straggler-p 0.0 stands
@@ -210,9 +214,6 @@ def main():
             ap.error("--rank-uplink-gbps solves k_budgets, which conflicts "
                      "with an explicit --plan (per-rank budgets live in "
                      "the plan's k_per_block)")
-        axis = dict(zip(mesh.axis_names, mesh.devices.shape))
-        n_code = int(np.prod([axis[a] for a in spec.coding.coding_axes
-                              if a in axis])) or 1
         if args.plan == "auto":
             plan = _auto_plan(args, spec, n_code, trace_path, args.plan_out)
         else:

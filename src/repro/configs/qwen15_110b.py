@@ -3,7 +3,7 @@
 Memory plan: 110B params cannot replicate over the data axis (27.5 GB/chip
 f32 at TP=16 alone), so parameters/optimizer are FSDP-sharded over 'data'
 and gradient coding engages across PODS only.  On the single-pod mesh the
-coding axis degenerates to 1 rank -> dense baseline (DESIGN.md Sec. 4/5).
+coding axis is 1 rank, which runs Algorithm 1 with no stragglers (p = 0).
 """
 from repro.nn.config import ModelConfig
 from .common import ArchSpec, CodingPlan, lm_shapes

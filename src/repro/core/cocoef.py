@@ -37,6 +37,8 @@ from jax import lax
 from repro.compat import axis_size
 from repro.kernels import ref as kernel_ref
 from repro.kernels.ops import backend_use_pallas
+from repro.kernels.sign_pack import G_BLK as _SIGN_G_BLK
+from repro.kernels.topk_pack import R_BLK as _TOPK_R_BLK
 from repro.obs.metrics import MetricsFrame, norm_sq
 from .collectives import (CodingCollectiveConfig, DenseWire, SignWire,
                           SparseWire, WireFormat, coded_allreduce_start,
@@ -105,12 +107,16 @@ class CocoEFConfig:
 
     @property
     def pad_multiple(self) -> int:
-        """Per-bucket flat-size alignment (feeds `padded_size`): the sign
-        group always participates (phase-2 re-compression packs the chunk
-        with `group_size`), joined with the sparse block when active."""
+        """Per-bucket flat-size alignment (feeds `padded_size`): a whole
+        row tile of the wire kernels, so every train-path chunk takes the
+        Pallas path (`kernels.ops.resolve_use_pallas` never falls back).
+        The sign tile always participates (phase-2 re-compression packs
+        the chunk with `group_size`), joined with the sparse tile when
+        active."""
+        tile = _SIGN_G_BLK * self.group_size
         if self.compressor == "block_topk":
-            return math.lcm(self.group_size, self.block_size)
-        return self.group_size
+            return math.lcm(tile, _TOPK_R_BLK * self.block_size)
+        return tile
 
 
 # --------------------------------------------------------------------------
@@ -182,6 +188,17 @@ def _check_rank_budgets(wire, mask: jnp.ndarray) -> None:
 
 def _joined(parts: List[jnp.ndarray]) -> jnp.ndarray:
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+def _flat_outputs(*xs: jnp.ndarray):
+    """Hand the step its flat (n,) results as arrays of their own.  The
+    wire kernels and their jnp twins work on (rows, group) tiles; left
+    free, the TPU compiler fuses that relayout into every consumer (the
+    optimizer update and the unflatten slices of each leaf), and its code
+    generation then grows with n: minutes per compile at 10^8 coords.
+    The barriers change no value; one per array keeps each array's
+    varying mesh axes its own."""
+    return tuple(lax.optimization_barrier(x) for x in xs)
 
 
 class _BucketSchedule:
@@ -293,7 +310,8 @@ def _cocoef_update_impl(g_local: jnp.ndarray, e_local: jnp.ndarray,
             payload = wire.apply_rank_budget(
                 wire.fused_pack(acc_b, use_pallas=use_pallas), my_idx)
             sched.submit(wire, payload)
-        return _joined(sched.collect()), e_local
+        (ghat,) = _flat_outputs(_joined(sched.collect()))
+        return ghat, e_local
 
     # cocoef: fused accumulate + compress + error update per bucket.
     # Under the pipelined schedule bucket b's local step is traced before
@@ -321,9 +339,8 @@ def _cocoef_update_impl(g_local: jnp.ndarray, e_local: jnp.ndarray,
                 g_b, e_b, gamma, my_mask, use_pallas=use_pallas, want_c=False)
         sched.submit(wire, payload)
         e_parts.append(e_new_b)
-    ghat = _joined(sched.collect())
-    new_e = _joined(e_parts)
-    return ghat, new_e.astype(jnp.dtype(cfg.ef_dtype))
+    return _flat_outputs(_joined(sched.collect()),
+                         _joined(e_parts).astype(jnp.dtype(cfg.ef_dtype)))
 
 
 def _cocoef_update_metrics(g_local: jnp.ndarray, e_local: jnp.ndarray,
@@ -398,7 +415,7 @@ def _cocoef_update_metrics(g_local: jnp.ndarray, e_local: jnp.ndarray,
             bytes_rank = bytes_rank + rb
             bucket_rows.append(rb)
             sched.submit(wire, payload)
-        ghat = _joined(sched.collect())
+        (ghat,) = _flat_outputs(_joined(sched.collect()))
         frame = finish(ghat, e_local, bytes_rank, np.stack(bucket_rows),
                        acc_sq, c_sq, dot)
         return ghat, e_local, frame
@@ -426,8 +443,9 @@ def _cocoef_update_metrics(g_local: jnp.ndarray, e_local: jnp.ndarray,
         bucket_rows.append(rb)
         sched.submit(wire, payload)
         e_parts.append(e_new_b)
-    ghat = _joined(sched.collect())
-    new_e = _joined(e_parts).astype(jnp.dtype(cfg.ef_dtype))
+    ghat, new_e = _flat_outputs(
+        _joined(sched.collect()),
+        _joined(e_parts).astype(jnp.dtype(cfg.ef_dtype)))
     frame = finish(ghat, new_e, bytes_rank, np.stack(bucket_rows),
                    acc_sq, c_sq, dot)
     return ghat, new_e, frame

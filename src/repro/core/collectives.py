@@ -80,7 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.compat import axis_size
+from repro.compat import all_gather_invariant, axis_size
 from repro.kernels import ops as kernel_ops, ref as kernel_ref
 from repro.kernels.sign_pack import G_BLK as _SIGN_G_BLK
 from repro.kernels.topk_pack import R_BLK as _TOPK_R_BLK
@@ -649,12 +649,12 @@ def _phase2_gather(chunk_sum: jnp.ndarray,
         # beyond-paper: re-sign-compress the aggregate (server-side EF is
         # maintained by the caller via the returned residual if desired)
         w2, s2 = sign_pack(chunk_sum.astype(jnp.float32), cfg.group_size)
-        w2g = lax.all_gather(w2, cfg.chunk_axis, axis=0, tiled=True)
-        s2g = lax.all_gather(s2, cfg.chunk_axis, axis=0, tiled=True)
+        w2g = all_gather_invariant(w2, cfg.chunk_axis, axis=0, tiled=True)
+        s2g = all_gather_invariant(s2, cfg.chunk_axis, axis=0, tiled=True)
         return sign_unpack(w2g, s2g, cfg.group_size)
     payload2 = chunk_sum.astype(cfg.phase2_dtype)
-    return lax.all_gather(payload2, cfg.chunk_axis, axis=0,
-                          tiled=True).astype(jnp.float32)
+    return all_gather_invariant(payload2, cfg.chunk_axis, axis=0,
+                                tiled=True).astype(jnp.float32)
 
 
 def two_phase_coded_allreduce(

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.kernels.ref import mul_add
+from repro.kernels.ref import mul_add, scan_zeros
 from .compression import Compressor
 
 __all__ = [
@@ -84,7 +84,8 @@ def _masked_sum(mask: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     def body(acc, inp):
         mi, ci = inp
         return acc + mi * ci, None
-    return lax.scan(body, jnp.zeros(c.shape[1:], c.dtype), (m, c))[0]
+    return lax.scan(body, scan_zeros(c.shape[1:], m, c, dtype=c.dtype),
+                    (m, c))[0]
 
 
 def _per_device_keys(key: Optional[jax.Array], step, n: int):

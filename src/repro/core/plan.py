@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import math
 from typing import Any, Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
@@ -183,13 +182,6 @@ class PlanSpec:
             raise ValueError("rank_wire_bytes needs num_ranks (pass it or "
                              "set PlanSpec.num_ranks)")
         return self.wire(n, 1).rank_wire_bytes(n, m)
-
-    @property
-    def pad_multiple(self) -> int:
-        """Per-bucket flat-size alignment (mirrors CocoEFConfig)."""
-        if self.compressor == "block_topk":
-            return math.lcm(self.group_size, self.block_size)
-        return self.group_size
 
     @property
     def overlap(self) -> bool:

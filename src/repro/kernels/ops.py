@@ -11,6 +11,7 @@ from __future__ import annotations
 import warnings
 
 import jax
+from jax.experimental.pallas import tpu as pltpu
 
 from . import (ref, sign_pack as sp, topk_block as tb, topk_fast as tf,
                topk_pack as tp)
@@ -18,6 +19,13 @@ from . import (ref, sign_pack as sp, topk_block as tb, topk_fast as tf,
 
 def default_use_pallas() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret():
+    """Mosaic compiles the kernels on a TPU.  Elsewhere they run in
+    Pallas's TPU interpreter, which (unlike the HLO interpreter) traces
+    inside a shard_map with check_vma on."""
+    return False if default_use_pallas() else pltpu.InterpretParams()
 
 
 BACKENDS = ("auto", "pallas", "jnp")
@@ -76,7 +84,7 @@ def sign_pack(x, group_size: int, use_pallas=None):
     use = default_use_pallas() if use_pallas is None else use_pallas
     if use:
         return sp.sign_pack(x, group_size,
-                            interpret=jax.default_backend() != "tpu")
+                            interpret=_interpret())
     return ref.sign_pack_ref(x, group_size)
 
 
@@ -90,7 +98,7 @@ def ef_sign_fused(g, e, gamma, mask_self, group_size: int,
     if use:
         return sp.ef_sign_fused(g, e, gamma, mask_self, group_size,
                                 want_c=want_c,
-                                interpret=jax.default_backend() != "tpu")
+                                interpret=_interpret())
     w, s, c, e_new = ref.ef_sign_fused_ref(g, e, gamma, mask_self, group_size)
     return w, s, (c if want_c else None), e_new
 
@@ -99,7 +107,7 @@ def sign_decode_reduce(words, scales, mask, group_size: int, use_pallas=None):
     use = default_use_pallas() if use_pallas is None else use_pallas
     if use:
         return sp.sign_decode_reduce(words, scales, mask, group_size,
-                                     interpret=jax.default_backend() != "tpu")
+                                     interpret=_interpret())
     return ref.sign_decode_reduce_scan(words, scales, mask, group_size)
 
 
@@ -110,7 +118,7 @@ def ef_topk_fused(g, e, gamma, mask_self, k: int, block_size: int,
     if use:
         return tp.ef_topk_fused(g, e, gamma, mask_self, k, block_size,
                                 want_c=want_c, value_dtype=value_dtype,
-                                interpret=jax.default_backend() != "tpu")
+                                interpret=_interpret())
     return tf.ef_topk_fused_fast(g, e, gamma, mask_self, k, block_size,
                                  value_dtype=value_dtype, want_c=want_c)
 
@@ -126,7 +134,7 @@ def block_topk(x, k: int, block_size: int, use_pallas=None):
     use = default_use_pallas() if use_pallas is None else use_pallas
     if use:
         return tb.block_topk(x, k, block_size,
-                             interpret=jax.default_backend() != "tpu")
+                             interpret=_interpret())
     return ref.block_topk_ref(x, k, block_size)
 
 
@@ -134,7 +142,7 @@ def topk_pack(x, k: int, block_size: int, use_pallas=None):
     use = default_use_pallas() if use_pallas is None else use_pallas
     if use:
         return tp.topk_pack(x, k, block_size,
-                            interpret=jax.default_backend() != "tpu")
+                            interpret=_interpret())
     return tf.topk_pack_fast(x, k, block_size)
 
 
@@ -148,6 +156,6 @@ def topk_decode_reduce(indices, values, scales, mask, block_size: int,
     if use:
         return tp.topk_decode_reduce(indices, values, scales, mask,
                                      block_size,
-                                     interpret=jax.default_backend() != "tpu")
+                                     interpret=_interpret())
     return ref.topk_decode_reduce_scan(indices, values, scales, mask,
                                        block_size)

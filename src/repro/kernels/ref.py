@@ -10,6 +10,18 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+from repro.compat import vma
+
+
+def scan_zeros(shape, *operands, dtype=jnp.float32) -> jnp.ndarray:
+    """Zeros that start a rank-order accumulation scan over `operands`.
+    Inside a shard_map (check_vma on) the scan carry must vary over the
+    same mesh axes as what the body adds into it; a bare `jnp.zeros`
+    varies over none, so the zeros are cast to the operands' axes."""
+    return lax.pcast(jnp.zeros(shape, dtype), tuple(sorted(vma(*operands))),
+                     to="varying")
 
 
 def mul_add(gamma, g: jnp.ndarray, e: jnp.ndarray) -> jnp.ndarray:
@@ -86,8 +98,8 @@ def sign_decode_reduce_scan(words: jnp.ndarray, scales: jnp.ndarray,
     def body(acc, inp):
         w, s, m = inp
         return acc + m * sign_unpack_ref(w, s, group_size), None
-    return jax.lax.scan(body, jnp.zeros((n,), jnp.float32),
-                        (words, scales, mask))[0]
+    return lax.scan(body, scan_zeros((n,), words, scales, mask),
+                    (words, scales, mask))[0]
 
 
 def topk_pack_ref(x: jnp.ndarray, k: int, block_size: int
@@ -158,8 +170,8 @@ def dense_decode_reduce_scan(values: jnp.ndarray, mask: jnp.ndarray
     def body(acc, inp):
         v, m = inp
         return acc + m * v.astype(jnp.float32), None
-    return jax.lax.scan(body, jnp.zeros((n,), jnp.float32),
-                        (values, mask))[0]
+    return lax.scan(body, scan_zeros((n,), values, mask),
+                    (values, mask))[0]
 
 
 def topk_unpack_ref(indices: jnp.ndarray, values: jnp.ndarray,
@@ -194,8 +206,8 @@ def topk_decode_reduce_scan(indices: jnp.ndarray, values: jnp.ndarray,
     def body(acc, inp):
         i, v, s, m = inp
         return acc + m * topk_unpack_ref(i, v, s, block_size), None
-    return jax.lax.scan(body, jnp.zeros((n,), jnp.float32),
-                        (indices, values, scales, mask))[0]
+    return lax.scan(body, scan_zeros((n,), indices, values, scales, mask),
+                    (indices, values, scales, mask))[0]
 
 
 def block_topk_ref(x: jnp.ndarray, k: int, block_size: int) -> jnp.ndarray:

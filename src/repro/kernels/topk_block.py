@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.compat import vary_alike
+
 R_BLK = 8  # rows (blocks) per grid step
 
 
@@ -145,12 +147,15 @@ def block_topk(x: jnp.ndarray, k: int, block_size: int,
     """x: (n,) with n % (R_BLK * block_size) == 0 -> sparsified (n,)."""
     n = x.shape[0]
     rows = n // block_size
+    args, axes = vary_alike(x.reshape(rows, block_size))
+    sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
     out = pl.pallas_call(
         functools.partial(_topk_kernel, k=k),
+        name="block_topk",
         grid=(rows // R_BLK,),
         in_specs=[pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, block_size), x.dtype),
+        out_shape=sds((rows, block_size), x.dtype),
         interpret=interpret,
-    )(x.reshape(rows, block_size))
+    )(*args)
     return out.reshape(-1)

@@ -18,6 +18,7 @@ BlockSpec is VPU aligned:
   indices block (R_BLK, k)           i32  VMEM
   values block  (R_BLK, k)           f32  VMEM
   scales block  (R_BLK, 1)           f32  VMEM
+  gamma / mask                       f32  SMEM  (scalars)
 
 The narrow wire dtypes (uint16 indices, bf16 values) are cast OUTSIDE the
 kernel by SparseWire.pack — Mosaic keeps 32-bit lanes internally.
@@ -34,10 +35,14 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.compat import vary_alike
 from repro.kernels.topk_block import block_select
 
 R_BLK = 8  # blocks (rows) per grid step
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole (k,) f32 scalar array
 
 
 def _topk_pack_kernel(x_ref, idx_ref, val_ref, scale_ref, *, k: int):
@@ -60,8 +65,11 @@ def topk_pack(x: jnp.ndarray, k: int, block_size: int, interpret: bool = True
         raise ValueError(f"topk_pack needs n % (R_BLK*block_size) == 0, got "
                          f"n={n}, R_BLK={R_BLK}, block_size={block_size}")
     grid = (rows // R_BLK,)
+    args, axes = vary_alike(x.reshape(rows, block_size))
+    sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
     idx, val, scale = pl.pallas_call(
         functools.partial(_topk_pack_kernel, k=k),
+        name="topk_pack",
         grid=grid,
         in_specs=[pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0))],
         out_specs=[
@@ -70,12 +78,12 @@ def topk_pack(x: jnp.ndarray, k: int, block_size: int, interpret: bool = True
             pl.BlockSpec((R_BLK, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, k), jnp.int32),
-            jax.ShapeDtypeStruct((rows, k), jnp.float32),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            sds((rows, k), jnp.int32),
+            sds((rows, k), jnp.float32),
+            sds((rows, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x.reshape(rows, block_size))
+    )(*args)
     return idx, val, scale.reshape(-1)
 
 
@@ -130,19 +138,23 @@ def ef_topk_fused(g: jnp.ndarray, e: jnp.ndarray, gamma, mask_self,
         raise ValueError(f"ef_topk_fused needs n % (R_BLK*block_size) == 0, "
                          f"got n={n}, R_BLK={R_BLK}, block_size={block_size}")
     grid = (rows // R_BLK,)
-    gamma = jnp.asarray(gamma, jnp.float32).reshape(1)
-    mask_self = jnp.asarray(mask_self, jnp.float32).reshape(1)
+    args, axes = vary_alike(
+        g.reshape(rows, block_size), e.reshape(rows, block_size),
+        jnp.asarray(gamma, jnp.float32).reshape(1),
+        jnp.asarray(mask_self, jnp.float32).reshape(1))
+    sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
     full = [pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
-            jax.ShapeDtypeStruct((rows, block_size), jnp.float32)]
+            sds((rows, block_size), jnp.float32)]
     outs = pl.pallas_call(
         functools.partial(_ef_topk_fused_kernel, k=k, want_c=want_c,
                           value_dtype=value_dtype),
+        name="ef_topk_fused",
         grid=grid,
         in_specs=[
             pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
             pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=[
             pl.BlockSpec((R_BLK, k), lambda i: (i, 0)),
@@ -150,13 +162,12 @@ def ef_topk_fused(g: jnp.ndarray, e: jnp.ndarray, gamma, mask_self,
             pl.BlockSpec((R_BLK, 1), lambda i: (i, 0)),
         ] + [full[0]] * (1 + want_c),
         out_shape=[
-            jax.ShapeDtypeStruct((rows, k), jnp.int32),
-            jax.ShapeDtypeStruct((rows, k), jnp.float32),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            sds((rows, k), jnp.int32),
+            sds((rows, k), jnp.float32),
+            sds((rows, 1), jnp.float32),
         ] + [full[1]] * (1 + want_c),
         interpret=interpret,
-    )(g.reshape(rows, block_size), e.reshape(rows, block_size), gamma,
-      mask_self)
+    )(*args)
     idx, val, scale = outs[0], outs[1], outs[2]
     c = outs[3].reshape(-1) if want_c else None
     return idx, val, scale.reshape(-1), c, outs[-1].reshape(-1)
@@ -183,18 +194,23 @@ def topk_decode_reduce(indices: jnp.ndarray, values: jnp.ndarray,
         raise ValueError(f"topk_decode_reduce needs rows % R_BLK == 0, got "
                          f"rows={rows}, R_BLK={R_BLK}")
     grid = (rows // R_BLK,)
+    args, axes = vary_alike(
+        indices.astype(jnp.int32), values.astype(jnp.float32),
+        scales.reshape(N, rows, 1).astype(jnp.float32),
+        mask.astype(jnp.float32))
+    sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
     out = pl.pallas_call(
         functools.partial(_topk_decode_reduce_kernel, k=k, n_senders=N),
+        name="topk_decode_reduce",
         grid=grid,
         in_specs=[
             pl.BlockSpec((N, R_BLK, k), lambda i: (0, i, 0)),
             pl.BlockSpec((N, R_BLK, k), lambda i: (0, i, 0)),
             pl.BlockSpec((N, R_BLK, 1), lambda i: (0, i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, block_size), jnp.float32),
+        out_shape=sds((rows, block_size), jnp.float32),
         interpret=interpret,
-    )(indices.astype(jnp.int32), values.astype(jnp.float32),
-      scales.reshape(N, rows, 1).astype(jnp.float32), mask)
+    )(*args)
     return out.reshape(-1)

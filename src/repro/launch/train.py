@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.common import ArchSpec, ShapeCfg
 from repro.core import coding
 from repro.core.coding_state import CodingPlan, CodingState
@@ -274,11 +273,10 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
                         if a in mesh.axis_names)
     n_code = int(np.prod([axis_sizes[a] for a in coding_axes])) \
         if coding_axes else 1
-    if n_code <= 1:
-        mode = "dense"               # coding degenerates (documented)
-        p_strag = 0.0
-    else:
-        p_strag = spec.coding.straggler_p
+    # one coding rank still runs the mode asked for (EF, the wire kernels,
+    # the all_to_all over a size-1 axis), but never straggles: a lone rank
+    # that straggles would stop training, so its p is 0 (and d is 1)
+    p_strag = spec.coding.straggler_p if n_code > 1 else 0.0
 
     # ---- the effective deployment plan (single source of truth) ----------
     # `plan` carries every (d, wire, k, schedule, backend) knob from here
@@ -465,12 +463,16 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
         frame_abs = MetricsFrame.abstract(max(n_code, 1), plan.num_buckets)
         out_specs += (frame_out_specs(frame_abs, mesh.axis_names),)
 
-    agg = compat.shard_map(
-        agg_body, mesh,
+    # check_vma stays off here: the flat vector joins model-sharded and
+    # model-replicated leaves, so a replicated leaf's update is typed as
+    # varying over `model` (and with model > 1 a sign group or top-k block
+    # that straddles both kinds of leaf really does differ across model
+    # shards).  Every other shard_map in the repo runs with the check on.
+    agg = jax.shard_map(
+        agg_body, mesh=mesh,
         in_specs=(params_in_specs, grads_in_specs, state_spec, opt_specs,
                   P(), P()),
-        out_specs=out_specs,
-        axis_names=all_axes, check=False)
+        out_specs=out_specs, axis_names=all_axes, check_vma=False)
 
     # =======================================================================
     # full train step
@@ -582,10 +584,14 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
 
     def init_state(key):
         params = jax.jit(model.init, out_shardings=pshard)(key)
-        e = jnp.zeros(state_shape, jnp.dtype(run.ef_dtype))
-        e = jax.device_put(e, state_sharding)
-        opt = tuple(jax.device_put(jnp.zeros(state_shape, jnp.float32),
-                                   state_sharding) for _ in range(n_opt))
+
+        def zeros(dtype):
+            # made in place on every device: the whole (n_code * flat,)
+            # vector need not fit on one
+            return jax.jit(lambda: jnp.zeros(state_shape, dtype),
+                           out_shardings=state_sharding)()
+        e = zeros(jnp.dtype(run.ef_dtype))
+        opt = tuple(zeros(jnp.float32) for _ in range(n_opt))
         return params, e, opt
 
     return TrainSetup(

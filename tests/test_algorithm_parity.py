@@ -26,6 +26,21 @@ def test_reference_vs_mesh_parity_sign_quick():
     """, timeout=600)
 
 
+@pytest.mark.parametrize("comp", ["sign", "block_topk", "identity"])
+def test_one_rank_cocoef_matches_reference_loop(comp):
+    """One coding rank (the one-chip train step) runs Algorithm 1 itself,
+    error feedback and the wire over a size-1 all_to_all, and never
+    straggles: bit-for-bit the N=1 reference EF loop, on both backends."""
+    run_sub(f"""
+    from repro.launch.parity import assert_parity, run_parity
+    for backend in ("jnp", "pallas"):
+        rep = run_parity({comp!r}, T=10, N=1, shards=1, d=1, p=0.0,
+                         backend=backend)
+        assert_parity(rep)
+        assert rep["loss_ref"] < rep["loss_start"], (backend, rep)
+    """, devices=1, timeout=600)
+
+
 def test_dynamic_coding_state_parity_sign_quick():
     """Tier-1 elastic-plane gate: with the rate estimate pinned to the
     oracle rates, the dynamic CodingState trajectory (W recomputed by

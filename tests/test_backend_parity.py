@@ -100,7 +100,7 @@ def test_backend_parity_cocoef_update_sweep():
                               mesh, in_specs=(P(("data", "model")),) * 2
                               + (P(),),
                               out_specs=(P(("data", "model")),) * 2,
-                              axis_names={"data", "model"}, check=False)
+                              axis_names={"data", "model"})
                 jf = jax.jit(f)
                 outs[backend] = [jf(g, e, mask) for mask in masks]
             for (g1, e1), (g2, e2) in zip(outs["jnp"], outs["pallas"]):
@@ -131,7 +131,7 @@ def test_backend_parity_coco_mode():
                                                        ccfg),
                           mesh, in_specs=(P(("data", "model")),) * 2,
                           out_specs=(P(("data", "model")),) * 2,
-                          axis_names={"data", "model"}, check=False)
+                          axis_names={"data", "model"})
             outs.append(jax.jit(f)(g, e))
         assert np.array_equal(np.asarray(outs[0][0]), np.asarray(outs[1][0])), comp
         assert np.array_equal(np.asarray(outs[0][1]), np.asarray(outs[1][1])), comp
@@ -276,7 +276,7 @@ def test_schedule_parity_serial_vs_pipelined():
                           mesh, in_specs=(P(("data", "model")),) * 2
                           + (P(),),
                           out_specs=(P(("data", "model")),) * 2,
-                          axis_names={"data", "model"}, check=False)
+                          axis_names={"data", "model"})
             jf = jax.jit(f)
             outs[sched] = [jf(g, e, m) for m in masks]
         for (g1, e1), (g2, e2) in zip(outs["serial"], outs["pipelined"]):

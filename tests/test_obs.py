@@ -543,7 +543,7 @@ def test_metrics_disabled_hlo_identical_per_wire_and_backend():
         def lower2(fn):
             f = shard_map(lambda g, e: fn(g, e, mask, 0.05, cfg), mesh,
                           in_specs=(spec,) * 2, out_specs=(spec,) * 2,
-                          axis_names=axis, check=False)
+                          axis_names=axis)
             return jax.jit(f).lower(gs, gs).as_text()
 
         off = lower2(cocoef_update)          # default want_metrics=False
@@ -560,7 +560,7 @@ def test_metrics_disabled_hlo_identical_per_wire_and_backend():
         f_on = shard_map(body_on, mesh, in_specs=(spec,) * 2,
                          out_specs=(spec, spec,
                                     frame_out_specs(fa, mesh.axis_names)),
-                         axis_names=axis, check=False)
+                         axis_names=axis)
         on = jax.jit(f_on).lower(gs, gs).as_text()
         assert counts(on) == counts(off), \\
             f"metrics added collectives: {cfg}: " \\
@@ -621,7 +621,7 @@ def test_shard_map_per_rank_metrics_match_ledger():
         f = jax.jit(shard_map(
             body, mesh, in_specs=(spec,) * 2,
             out_specs=(spec, spec, frame_out_specs(fa, mesh.axis_names)),
-            axis_names=axis, check=False))
+            axis_names=axis))
         ghat, e_new, grid = f(g, e)
         tel = frame_to_host(jax.device_get(reduce_frame_grid(
             grid, mesh.axis_names, cfg.coding_axes)))

@@ -316,7 +316,7 @@ def test_cocoef_update_per_rank_budgets_match_oracle():
     f = shard_map(lambda gg, ee: cocoef_update(gg, ee, mask, gamma, cfg),
                   mesh, in_specs=(P(("data", "model")),) * 2,
                   out_specs=(P(("data", "model")),) * 2,
-                  axis_names={"data", "model"}, check=False)
+                  axis_names={"data", "model"})
     ghat, e_new = jax.jit(f)(g, e)
 
     # oracle: per coding rank, budget-k roundtrip + EF; ghat = masked sum
@@ -343,7 +343,7 @@ def test_cocoef_update_per_rank_budgets_match_oracle():
     fb = shard_map(lambda gg, ee: cocoef_update(gg, ee, mask, gamma, bad),
                    mesh, in_specs=(P(("data", "model")),) * 2,
                    out_specs=(P(("data", "model")),) * 2,
-                   axis_names={"data", "model"}, check=False)
+                   axis_names={"data", "model"})
     try:
         jax.jit(fb)(g, e)
         raise AssertionError("short per-rank budget tuple not caught")
@@ -447,7 +447,7 @@ def test_all_straggler_step_through_cocoef_update():
     f = shard_map(lambda gg, ee: cocoef_update(gg, ee, zero, 0.1, cfg),
                   mesh, in_specs=(P(("data", "model")),) * 2,
                   out_specs=(P(("data", "model")),) * 2,
-                  axis_names={"data", "model"}, check=False)
+                  axis_names={"data", "model"})
     ghat, e_new = jax.jit(f)(g, e)
     assert np.all(np.asarray(ghat) == 0.0)
     assert np.array_equal(np.asarray(e_new), np.asarray(e))

@@ -60,14 +60,14 @@ def test_iid_through_cocoef_update_hook_bit_for_bit():
             gg, ee, coding.straggler_mask(key, ss, 4, p), 0.1, ccfg),
         mesh, in_specs=(P(("data", "model")),) * 2 + (P(),),
         out_specs=(P(("data", "model")),) * 2,
-        axis_names={"data", "model"}, check=False)
+        axis_names={"data", "model"})
     hooked = shard_map(
         lambda gg, ee, ss: cocoef_update(
             gg, ee, None, 0.1, ccfg, mask_provider=proc.mask, key=key,
             step=ss),
         mesh, in_specs=(P(("data", "model")),) * 2 + (P(),),
         out_specs=(P(("data", "model")),) * 2,
-        axis_names={"data", "model"}, check=False)
+        axis_names={"data", "model"})
     jl, jh = jax.jit(legacy), jax.jit(hooked)
     for t in (0, 3, 17):
         (g1, e1), (g2, e2) = jl(g, e, jnp.int32(t)), jh(g, e, jnp.int32(t))
