@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracing import span
+
 __all__ = ["SyntheticLMConfig", "synthetic_lm_batch", "subset_batch_for_rank",
            "coded_train_batch", "elastic_train_batch", "coded_batch_stream",
            "prefetch_to_device", "PrefetchStats", "host_stream"]
@@ -221,9 +223,10 @@ class _DevicePrefetch:
         try:
             for item in self._it:
                 t0 = time.perf_counter()
-                item = (jax.device_put(item, self._shardings)
-                        if self._shardings is not None
-                        else jax.device_put(item))
+                with span("repro.feed.put"):
+                    item = (jax.device_put(item, self._shardings)
+                            if self._shardings is not None
+                            else jax.device_put(item))
                 stats.device_put_s += time.perf_counter() - t0
                 t0 = time.perf_counter()
                 while not stop.is_set():

@@ -40,6 +40,7 @@ from repro.core.plan import PlanSpec
 from repro.nn import Model
 from repro.obs.metrics import (MetricsFrame, frame_out_specs,
                                reduce_frame_grid)
+from repro.obs.tracing import span
 from repro.optim import OptimizerConfig, apply_update, init_opt_state, \
     lr_schedule
 from repro.sharding import ctx, rules
@@ -409,56 +410,64 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
     n_leaves = len(jax.tree.leaves(pshapes))
 
     def agg_body(params, grads, e, opt, step, key):
-        # local leaf blocks; grads leaves carry leading coding dims of size 1
-        p_leaves = jax.tree.leaves(params)
-        g_leaves = jax.tree.leaves(grads)
-        p_flat, p_meta = flatten_local(p_leaves, nd_chunk,
-                                       cocoef_cfg.pad_multiple,
-                                       plan.num_buckets)
-        g_flat, _ = flatten_local(g_leaves, nd_chunk, cocoef_cfg.pad_multiple,
-                                  plan.num_buckets)
-        e_loc = e.reshape(-1)
-        opt_loc = tuple(o.reshape(-1) for o in opt)
+        # every op of stage 2 carries "stage2/" in its op_name, and the flat
+        # copies (leaves and state to flat vectors and back) carry
+        # stage2/flatten or stage2/unflatten; the layout copies the
+        # compiler adds around them carry no op_name at all
+        with jax.named_scope("stage2"):
+            # local leaf blocks; grads leaves carry leading coding dims of
+            # size 1
+            with jax.named_scope("flatten"):
+                p_flat, p_meta = flatten_local(
+                    jax.tree.leaves(params), nd_chunk,
+                    cocoef_cfg.pad_multiple, plan.num_buckets)
+                g_flat, _ = flatten_local(
+                    jax.tree.leaves(grads), nd_chunk,
+                    cocoef_cfg.pad_multiple, plan.num_buckets)
+                e_loc = e.reshape(-1)
+                opt_loc = tuple(o.reshape(-1) for o in opt)
 
-        gamma = gamma_fn(step)
-        mask_fn = straggler_proc.mask if straggler_proc is not None else \
-            (lambda k, s: jnp.ones((max(n_code, 1),), jnp.float32))
+            gamma = gamma_fn(step)
+            mask_fn = straggler_proc.mask if straggler_proc is not None \
+                else (lambda k, s: jnp.ones((max(n_code, 1),), jnp.float32))
 
-        if run.metrics:
-            ghat, e_new, frame = cocoef_update(
-                g_flat, e_loc, None, gamma, cocoef_cfg,
-                mask_provider=mask_fn, key=key, step=step, want_metrics=True)
-            p_new_flat, opt_new, onorms = apply_update(
-                run.optimizer, p_flat, ghat, opt_loc, step, gamma,
-                want_norms=True)
-            frame = frame.replace(update_norm_sq=onorms["update_norm_sq"],
-                                  param_norm_sq=onorms["param_norm_sq"])
-        else:
-            ghat, e_new = cocoef_update(g_flat, e_loc, None, gamma,
-                                        cocoef_cfg, mask_provider=mask_fn,
-                                        key=key, step=step)
-            p_new_flat, opt_new = apply_update(run.optimizer, p_flat, ghat,
-                                               opt_loc, step, gamma)
-        new_leaves = unflatten_local(p_new_flat, p_meta)
-        params_new = jax.tree.unflatten(jax.tree.structure(params), new_leaves)
-        gnorm = jnp.sqrt(jnp.sum(ghat * ghat))          # local-slice norm
-        shape1 = (1,) * len(mesh_shape)
-        out = (params_new, e_new.reshape(shape1 + (flat_pad,)),
-               tuple(o.reshape(shape1 + (flat_pad,)) for o in opt_new),
-               gnorm.reshape(shape1))
-        if run.metrics:
-            # the gnorm idiom per leaf: grid-position dims of size 1 so the
-            # replicated frame lands as a (mesh..., leaf)-shaped output
-            out += (jax.tree.map(lambda l: l.reshape(shape1 + l.shape),
-                                 frame),)
-        return out
+            if run.metrics:
+                ghat, e_new, frame = cocoef_update(
+                    g_flat, e_loc, None, gamma, cocoef_cfg,
+                    mask_provider=mask_fn, key=key, step=step,
+                    want_metrics=True)
+                p_new_flat, opt_new, onorms = apply_update(
+                    run.optimizer, p_flat, ghat, opt_loc, step, gamma,
+                    want_norms=True)
+                frame = frame.replace(
+                    update_norm_sq=onorms["update_norm_sq"],
+                    param_norm_sq=onorms["param_norm_sq"])
+            else:
+                ghat, e_new = cocoef_update(g_flat, e_loc, None, gamma,
+                                            cocoef_cfg, mask_provider=mask_fn,
+                                            key=key, step=step)
+                p_new_flat, opt_new = apply_update(
+                    run.optimizer, p_flat, ghat, opt_loc, step, gamma)
+            shape1 = (1,) * len(mesh_shape)
+            with jax.named_scope("unflatten"):
+                new_leaves = unflatten_local(p_new_flat, p_meta)
+                params_new = jax.tree.unflatten(jax.tree.structure(params),
+                                                new_leaves)
+                out = (params_new, e_new.reshape(shape1 + (flat_pad,)),
+                       tuple(o.reshape(shape1 + (flat_pad,))
+                             for o in opt_new))
+            if run.metrics:
+                # grid-position dims of size 1 per leaf, so the replicated
+                # frame lands as a (mesh..., leaf)-shaped output
+                out += (jax.tree.map(lambda l: l.reshape(shape1 + l.shape),
+                                     frame),)
+            return out
 
     grads_in_specs = gspecs
     params_in_specs = pspecs
     opt_specs = tuple(state_spec for _ in range(n_opt))
 
-    out_specs = (params_in_specs, state_spec, opt_specs,
-                 P(*mesh.axis_names))
+    out_specs = (params_in_specs, state_spec, opt_specs)
     if run.metrics:
         frame_abs = MetricsFrame.abstract(max(n_code, 1), plan.num_buckets)
         out_specs += (frame_out_specs(frame_abs, mesh.axis_names),)
@@ -518,18 +527,14 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
         grads = jax.tree.map(
             lambda x, s: jax.lax.with_sharding_constraint(
                 x, NamedSharding(mesh, s)), grads, gspecs)
-        if run.metrics:
-            params_new, e_new, opt_new, gnorm, frame_grid = agg(
-                params, grads, e, opt, step, key)
-        else:
-            params_new, e_new, opt_new, gnorm = agg(params, grads, e, opt,
-                                                    step, key)
-        metrics = {"loss": losses.mean(), "gnorm_local": gnorm.max()}
+        out = agg(params, grads, e, opt, step, key)
+        params_new, e_new, opt_new = out[:3]
+        metrics = {"loss": losses.mean()}
         if run.metrics:
             # grid-replicated frame -> per-coding-rank / global step
             # telemetry; runs outside the shard_map, adds no collectives
             metrics["telemetry"] = reduce_frame_grid(
-                frame_grid, mesh.axis_names, coding_axes)
+                out[3], mesh.axis_names, coding_axes)
         return params_new, e_new, opt_new, metrics
 
     if run.elastic:
@@ -655,14 +660,20 @@ def make_batch_for_step(setup: TrainSetup, spec: ArchSpec, shape: ShapeCfg,
         # CodingState.W in-graph via subset_ids); the plan's CURRENT
         # allocation decides the placement, so an epoch bump takes effect
         # at the next batch without retracing (uniform load keeps shapes)
-        toks, wts, sids = pipeline.elastic_train_batch(
-            key, step, setup.coding_plan.allocation, per_subset, seq,
-            cfg.vocab_size)
+        with span("repro.feed.tokens"):
+            toks, wts, sids = pipeline.elastic_train_batch(
+                key, step, setup.coding_plan.allocation, per_subset, seq,
+                cfg.vocab_size)
         extra = {"subset_ids": sids}
     else:
-        W = setup_encode_weights(setup)
-        toks, wts = pipeline.coded_train_batch(
-            key, step, setup.allocation, W, per_subset, seq, cfg.vocab_size)
+        # W is made on the device, behind the step in flight: its host
+        # copy waits for that step
+        with span("repro.feed.weights"):
+            W = np.asarray(setup_encode_weights(setup))
+        with span("repro.feed.tokens"):
+            toks, wts = pipeline.coded_train_batch(
+                key, step, setup.allocation, W, per_subset, seq,
+                cfg.vocab_size)
         extra = {}
     if cfg.input_mode == "tokens":
         return {"inputs": toks, "weights": wts, **extra}
@@ -695,7 +706,11 @@ def batch_stream(setup: TrainSetup, spec: ArchSpec, shape: ShapeCfg, key,
             yield make_batch_for_step(setup, spec, shape, key, t, smoke=smoke)
             t += 1
 
+    def put(b):
+        with span("repro.feed.put"):
+            return jax.device_put(b, setup.batch_shardings)
+
     if prefetch < 1:
-        return (jax.device_put(b, setup.batch_shardings) for b in gen())
+        return (put(b) for b in gen())
     return pipeline.prefetch_to_device(gen(), size=prefetch,
                                        shardings=setup.batch_shardings)

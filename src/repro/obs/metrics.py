@@ -111,7 +111,7 @@ _REPL_MEAN = ("ghat_norm_sq", "update_norm_sq", "param_norm_sq")
 def frame_out_specs(frame_abs: MetricsFrame, axis_names: Sequence[str]):
     """shard_map out_specs for a frame whose leaves were reshaped to
     (1,)*len(axis_names) + leaf.shape inside the body (the same idiom the
-    train step uses for its per-device gnorm scalar)."""
+    train step uses for its per-device flat EF and optimizer state)."""
     from jax.sharding import PartitionSpec as P
     return jax.tree.map(
         lambda l: P(*axis_names, *([None] * l.ndim)), frame_abs)

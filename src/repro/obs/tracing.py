@@ -1,19 +1,22 @@
-"""Span tracing: in-graph named scopes + host wall-clock span timers.
+"""Span tracing: in-graph named scopes + host spans on the profiler's clock.
 
 Two complementary planes:
 
-  * Device plane — `scope(name)` is `jax.named_scope`: zero-cost HLO op
-    metadata so profiler dumps (and `jax.profiler.trace`) show the
-    pack / all_to_all / decode-reduce / optimizer phases of the coded
-    step.  The scopes are applied unconditionally on the hot path — they
-    change op *names* only, never the computation.
+  * Device plane — `jax.named_scope` scopes: zero-cost HLO op metadata so
+    profiler dumps (and `jax.profiler.trace`) show the stage-2 flat
+    copies, pack / all_to_all / decode-reduce and optimizer phases of the
+    coded step.  The scopes are applied unconditionally on the hot path —
+    they change op *names* only, never the computation.
 
-  * Host plane — `SpanRecorder` measures the phases jit cannot see:
-    batch wait, prefetch queue occupancy, device put, step dispatch, the
-    blocking result fetch.  Each `span()` also enters a
-    `jax.profiler.TraceAnnotation` so host spans line up with device
-    traces when the profiler is on.  Spans render to Chrome-trace JSON
-    via `repro.obs.trace_export.chrome_trace`.
+  * Host plane — `span(name)` is a bare `jax.profiler.TraceAnnotation`:
+    the program's own host spans (names starting "repro.", such as the
+    feed's `repro.feed.weights|tokens|put`) land in the profiler's trace
+    on the same clock as the device events, and cost one enter/exit when
+    the profiler is off.  `SpanRecorder` also keeps wall-clock spans in
+    memory (batch wait, prefetch queue occupancy, step dispatch, the
+    blocking result fetch) and renders them to Chrome-trace JSON via
+    `repro.obs.trace_export.chrome_trace`; each of its spans enters
+    `span`.
 """
 from __future__ import annotations
 
@@ -23,10 +26,14 @@ from typing import Dict, List, Optional
 
 import jax
 
-__all__ = ["scope", "SpanRecorder"]
+__all__ = ["span", "SpanRecorder"]
 
-# in-graph phase annotation (op-metadata only; safe inside jit/shard_map)
-scope = jax.named_scope
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span in the profiler's trace: `with span("repro.feed.put"):`.
+    Records nothing itself; without a running profiler it costs one
+    enter/exit."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 class SpanRecorder:
@@ -46,9 +53,9 @@ class SpanRecorder:
 
     @contextlib.contextmanager
     def span(self, name: str, tid: str = "host", **args):
-        """Time a host-side phase; also a profiler TraceAnnotation."""
+        """Time a host-side phase; also a profiler span (`span`)."""
         t0 = self.now()
-        with jax.profiler.TraceAnnotation(name):
+        with span(name):
             try:
                 yield
             finally:
