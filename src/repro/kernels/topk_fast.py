@@ -15,7 +15,7 @@ does nothing; the placement is the whole fix.
 The barrier changes no values — every function here is bit-for-bit equal
 to its kernels/ref.py counterpart, which deliberately stays barrier-free
 as the semantic oracle.  `kernels.ops` dispatches the jnp backend here;
-the Pallas kernels (topk_pack.py / topk_block.block_select) cover the
+the Pallas kernels (topk_pack.py / topk_block.select_blocks) cover the
 in-kernel TPU side with a sort-free threshold search.
 
 Quantized-transmission semantics (`value_dtype`): the fused step emits

@@ -4,24 +4,38 @@ Mirrors kernels/sign_pack.py for the SparseWire of
 `repro.core.collectives`: per contiguous block of `block_size` coords the
 wire carries the k largest-|.| entries as (in-block indices, values
 normalized by the per-block scale, the f32 scale).  Selection is
-`topk_block.block_select` — a sort-free per-row threshold search on the
-|x| bit patterns (31 monotone halving steps seeded by the block max) plus
-one compaction pass, replacing the old k-round argmax whose vector
-reductions grew linearly in k; tie-breaking matches
+`topk_block.select_blocks` — a sort-free threshold search on the |x| bit
+patterns (31 monotone halving steps seeded by the block max), then k
+rounds of (max magnitude, first position); tie-breaking matches
 kernels/ref.topk_pack_ref (lax.top_k: first occurrence wins).
 
-Tiling: the flat vector is processed as (rows of R_BLK blocks) x
-(block_size lanes); block_size is a multiple of 128 in production so every
-BlockSpec is VPU aligned:
+Tiling of `topk_pack` and `ef_topk_fused`: a grid step takes
+T = `topk_block.tile_blocks(rows)` blocks (1024 at the train path's
+size; the last step may be ragged) and transposes them in VMEM so the
+blocks run along the lanes:
 
-  x block       (R_BLK, block_size)  f32  VMEM
-  indices block (R_BLK, k)           i32  VMEM
-  values block  (R_BLK, k)           f32  VMEM
-  scales block  (R_BLK, 1)           f32  VMEM
-  gamma / mask                       f32  SMEM  (scalars)
+  g, e blocks     (T, block_size)  f32  VMEM, transposed in the kernel
+  indices block   (k, T)           i32  VMEM, lane-dense
+  values block    (k, T)           f32  VMEM, lane-dense
+  scales block    (1, T)           f32  VMEM, lane-dense
+  c, e' blocks    (T, block_size)  f32  VMEM
+  gamma / mask                     f32  SMEM  (scalars)
 
-The narrow wire dtypes (uint16 indices, bf16 values) are cast OUTSIDE the
-kernel by SparseWire.pack — Mosaic keeps 32-bit lanes internally.
+The wrappers hand back the payload as (rows, k); that transpose, and the
+narrow wire dtypes (uint16 indices, bf16 values), are XLA ops OUTSIDE the
+kernel (SparseWire.pack) — Mosaic keeps 32-bit lanes internally.
+
+Why: with 8 blocks on the sublanes and their coordinates on the lanes,
+each grid step's selection was a serial chain of about a hundred
+dependent cross-lane reductions over two vregs, latency-bound.  On a TPU
+v5e, for the 1,985,632 blocks of xlstm-1.3b's 508M-coordinate flat
+vector (k 8 of 256), `ef_topk_fused` took 1,796 ms of device time a
+train step, 7.2 us per 8-block grid step.  Blocks on lanes make every
+reduction of the selection elementwise VPU work over T independent
+lanes: 22.0 ms a train step, 11.3 us per 1024-block grid step.
+
+`topk_decode_reduce` keeps (R_BLK, block_size) tiles and builds the dense
+image with `_scatter_rows`.
 
 On this CPU container the kernels run with interpret=True (pure-JAX
 semantics) and are validated against kernels/ref.py; on real TPU the same
@@ -38,16 +52,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import vary_alike
-from repro.kernels.topk_block import block_select
+from repro.kernels.topk_block import select_blocks, tile_blocks
 
-R_BLK = 8  # blocks (rows) per grid step
+R_BLK = 8  # the sparse wire's row alignment (pad_multiple); decode grid step
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole (k,) f32 scalar array
 
 
+def _lanes_specs(k: int, rows: int, tile: int, sds):
+    """Lane-dense (k, rows) / (1, rows) blocks of idx, values and scales."""
+    specs = [pl.BlockSpec((k, tile), lambda i: (0, i)),
+             pl.BlockSpec((k, tile), lambda i: (0, i)),
+             pl.BlockSpec((1, tile), lambda i: (0, i))]
+    shapes = [sds((k, rows), jnp.int32), sds((k, rows), jnp.float32),
+              sds((1, rows), jnp.float32)]
+    return specs, shapes
+
+
 def _topk_pack_kernel(x_ref, idx_ref, val_ref, scale_ref, *, k: int):
-    x = x_ref[...].astype(jnp.float32)
-    idx, sval, scale = block_select(x, k)
+    xt = x_ref[...].astype(jnp.float32).T                        # (B, T)
+    idx, sval, scale, _ = select_blocks(xt, k)
     safe = jnp.where(scale == 0, 1.0, scale)
     idx_ref[...] = idx
     val_ref[...] = sval / safe
@@ -57,34 +81,23 @@ def _topk_pack_kernel(x_ref, idx_ref, val_ref, scale_ref, *, k: int):
 @functools.partial(jax.jit, static_argnames=("k", "block_size", "interpret"))
 def topk_pack(x: jnp.ndarray, k: int, block_size: int, interpret: bool = True
               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """x: (n,) f32, n % (R_BLK * block_size) == 0 ->
+    """x: (n,) f32, n % block_size == 0 ->
     (indices (n/B, k) i32, values (n/B, k) f32, scales (n/B,) f32)."""
-    n = x.shape[0]
-    rows = n // block_size
-    if n % (R_BLK * block_size):
-        raise ValueError(f"topk_pack needs n % (R_BLK*block_size) == 0, got "
-                         f"n={n}, R_BLK={R_BLK}, block_size={block_size}")
-    grid = (rows // R_BLK,)
+    rows = x.shape[0] // block_size
+    tile = tile_blocks(rows)
     args, axes = vary_alike(x.reshape(rows, block_size))
     sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
+    out_specs, out_shape = _lanes_specs(k, rows, tile, sds)
     idx, val, scale = pl.pallas_call(
         functools.partial(_topk_pack_kernel, k=k),
         name="topk_pack",
-        grid=grid,
-        in_specs=[pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((R_BLK, k), lambda i: (i, 0)),
-            pl.BlockSpec((R_BLK, k), lambda i: (i, 0)),
-            pl.BlockSpec((R_BLK, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            sds((rows, k), jnp.int32),
-            sds((rows, k), jnp.float32),
-            sds((rows, 1), jnp.float32),
-        ],
+        grid=(pl.cdiv(rows, tile),),
+        in_specs=[pl.BlockSpec((tile, block_size), lambda i: (i, 0))],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(*args)
-    return idx, val, scale.reshape(-1)
+    return idx.T, val.T, scale.reshape(-1)
 
 
 def _scatter_rows(idx, sval, shape):
@@ -103,20 +116,26 @@ def _ef_topk_fused_kernel(g_ref, e_ref, gamma_ref, mask_ref,
     gamma = gamma_ref[0]
     mask = mask_ref[0]
     e = e_ref[...].astype(jnp.float32)
-    acc = gamma * g_ref[...].astype(jnp.float32) + e                # (R, B)
-    idx, sval, scale = block_select(acc, k)
+    acc_t = (gamma * g_ref[...].astype(jnp.float32) + e).T          # (B, T)
+    idx, sval, scale, rank = select_blocks(acc_t, k)
     safe = jnp.where(scale == 0, 1.0, scale)
     # normalize -> wire precision -> denormalize IN-REGISTER: c is the
     # transmitted reconstruction (== topk_unpack of the payload), so the
     # error update tracks the wire without an unpack-of-pack round trip
     val = (sval / safe).astype(jnp.dtype(value_dtype)).astype(jnp.float32)
-    c = _scatter_rows(idx, val * safe, acc.shape)
+    # c from the payload's own products, put in place by rank: a product
+    # that fed `acc - c` directly could be contracted into an FMA (XLA on
+    # the CPU does), and e' would then no longer be acc - C(acc)
+    cv = val * safe
+    c_t = jnp.zeros_like(acc_t)
+    for j in range(k):                                           # static loop
+        c_t = jnp.where(rank == j, cv[j:j + 1], c_t)
     idx_ref[...] = idx
     val_ref[...] = val
     scale_ref[...] = safe
     if want_c:
-        out_refs[0][...] = c
-    out_refs[-1][...] = jnp.where(mask > 0, acc - c, e)
+        out_refs[0][...] = c_t.T
+    out_refs[-1][...] = jnp.where(mask > 0, (acc_t - c_t).T, e)
 
 
 @functools.partial(jax.jit,
@@ -128,49 +147,33 @@ def ef_topk_fused(g: jnp.ndarray, e: jnp.ndarray, gamma, mask_self,
     """Fused local COCO-EF step on the sparse wire: one HBM pass over g/e
     producing the wire payload (indices, values rounded to value_dtype,
     scales), the transmitted reconstruction C(acc) and the new error.
-    g, e: (n,) f32; gamma, mask_self: scalars.
+    g, e: (n,) f32, n % block_size == 0; gamma, mask_self: scalars.
     Semantics match kernels.ref.ef_topk_fused_ref bit-for-bit.
     want_c=False skips the full-vector c store (the train path only ships
     the payload; a custom call's outputs are not DCE-able)."""
-    n = g.shape[0]
-    rows = n // block_size
-    if n % (R_BLK * block_size):
-        raise ValueError(f"ef_topk_fused needs n % (R_BLK*block_size) == 0, "
-                         f"got n={n}, R_BLK={R_BLK}, block_size={block_size}")
-    grid = (rows // R_BLK,)
+    rows = g.shape[0] // block_size
+    tile = tile_blocks(rows)
     args, axes = vary_alike(
         g.reshape(rows, block_size), e.reshape(rows, block_size),
         jnp.asarray(gamma, jnp.float32).reshape(1),
         jnp.asarray(mask_self, jnp.float32).reshape(1))
     sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
-    full = [pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
-            sds((rows, block_size), jnp.float32)]
+    full = pl.BlockSpec((tile, block_size), lambda i: (i, 0))
+    out_specs, out_shape = _lanes_specs(k, rows, tile, sds)
     outs = pl.pallas_call(
         functools.partial(_ef_topk_fused_kernel, k=k, want_c=want_c,
                           value_dtype=value_dtype),
         name="ef_topk_fused",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
-            pl.BlockSpec((R_BLK, block_size), lambda i: (i, 0)),
-            _SMEM,
-            _SMEM,
-        ],
-        out_specs=[
-            pl.BlockSpec((R_BLK, k), lambda i: (i, 0)),
-            pl.BlockSpec((R_BLK, k), lambda i: (i, 0)),
-            pl.BlockSpec((R_BLK, 1), lambda i: (i, 0)),
-        ] + [full[0]] * (1 + want_c),
-        out_shape=[
-            sds((rows, k), jnp.int32),
-            sds((rows, k), jnp.float32),
-            sds((rows, 1), jnp.float32),
-        ] + [full[1]] * (1 + want_c),
+        grid=(pl.cdiv(rows, tile),),
+        in_specs=[full, full, _SMEM, _SMEM],
+        out_specs=out_specs + [full] * (1 + want_c),
+        out_shape=out_shape + [sds((rows, block_size), jnp.float32)]
+        * (1 + want_c),
         interpret=interpret,
     )(*args)
     idx, val, scale = outs[0], outs[1], outs[2]
     c = outs[3].reshape(-1) if want_c else None
-    return idx, val, scale.reshape(-1), c, outs[-1].reshape(-1)
+    return idx.T, val.T, scale.reshape(-1), c, outs[-1].reshape(-1)
 
 
 def _topk_decode_reduce_kernel(idx_ref, val_ref, scale_ref, mask_ref, out_ref,

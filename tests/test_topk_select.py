@@ -1,13 +1,16 @@
-"""Block-select top-k: the sort-free threshold search must BE `lax.top_k`.
+"""Block top-k selection: the sort-free threshold search must BE `lax.top_k`.
 
-`kernels.topk_block.block_select` (binary search on IEEE bit patterns +
-first-occurrence tie cut) is the in-kernel selection primitive of every
-sparse-wire Pallas kernel, and `kernels.topk_fast` is the barrier-fixed
-jnp hot path the train step runs on CPU.  The reference-vs-mesh parity
-gate demands that all three agree with `kernels/ref.py` (plain
-`lax.top_k`) BIT-FOR-BIT — indices, tie ORDER, values, scale — so these
-tests drive the selection through adversarial inputs: heavy magnitude
-ties, all-equal rows, all-zero rows, denormals, and k == block width.
+`kernels.topk_block.select_blocks` (binary search on IEEE bit patterns,
+then k rounds of (max magnitude, first position), with blocks on lanes)
+is the in-kernel selection primitive of every sparse-wire Pallas kernel,
+and `kernels.topk_fast` is the barrier-fixed jnp hot path the train step
+runs on CPU.  The reference-vs-mesh parity gate demands that all three
+agree with `kernels/ref.py` (plain `lax.top_k`) BIT-FOR-BIT — indices,
+tie ORDER, values, scale — so these tests drive the selection through
+adversarial inputs: heavy magnitude ties, all-equal rows, all-zero rows,
+denormals, and k == block width; and the kernels through block counts
+that fill whole tiles, leave a ragged last grid step, or fall short of
+one tile.
 
 Also covered here: the transmitted-reconstruction conservation for
 bfloat16 wire values (Sterbenz), and the warn-once guard on silent
@@ -24,7 +27,7 @@ from jax import lax
 
 from repro.kernels import ops, ref
 from repro.kernels import topk_fast as tf
-from repro.kernels.topk_block import block_select, block_select_mask
+from repro.kernels.topk_block import TILE_BLOCKS, select_blocks
 from repro.kernels.topk_pack import ef_topk_fused, topk_pack
 
 KINDS = ("normal", "ties", "equal", "denormal", "zeros")
@@ -44,46 +47,74 @@ def _rows(kind: str, seed: int, R: int, B: int) -> jnp.ndarray:
     return x.astype(jnp.float32)
 
 
+def _bitwise_equal(a, b, msg=""):
+    """Equal bit patterns: -0.0 differs from 0.0, NaN payloads count."""
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape, (msg, a.dtype, b.dtype)
+    if a.dtype.kind == "f":
+        a, b = a.view(f"u{a.itemsize}"), b.view(f"u{b.itemsize}")
+    np.testing.assert_array_equal(a, b, msg)
+
+
+_select = jax.jit(select_blocks, static_argnums=1)
+
+
 @settings(max_examples=20, deadline=None)
 @given(kind=st.sampled_from(KINDS), seed=st.integers(0, 100),
        k=st.sampled_from([1, 4, 16, 64]))
 def test_block_select_is_lax_top_k(kind, seed, k):
     """Indices (incl. tie order), signed values, and scale all bitwise
     equal to the lax.top_k selection on |x| — for every adversarial row
-    family, up to k == block width."""
+    family, up to k == block width.  Blocks run along the lanes."""
     B = 64
     x = _rows(kind, seed, 8, B)
-    idx, sval, scale = jax.jit(block_select, static_argnums=1)(x, k)
+    idx, sval, scale, _ = _select(x.T, k)
     topv, tidx = lax.top_k(jnp.abs(x), k)
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(tidx), kind)
-    np.testing.assert_array_equal(
-        np.asarray(sval), np.asarray(jnp.take_along_axis(x, tidx, -1)), kind)
-    np.testing.assert_array_equal(
-        np.asarray(scale[:, 0]),
-        np.asarray(jnp.max(jnp.abs(x), axis=-1)), kind)
+    _bitwise_equal(idx.T, tidx, kind)
+    _bitwise_equal(sval.T, jnp.take_along_axis(x, tidx, -1), kind)
+    _bitwise_equal(scale[0], jnp.max(jnp.abs(x), axis=-1), kind)
 
 
 @settings(max_examples=20, deadline=None)
 @given(kind=st.sampled_from(KINDS), seed=st.integers(0, 100),
        k=st.sampled_from([1, 7, 32, 128]))
 def test_block_select_mask_is_exact_topk_set(kind, seed, k):
-    """The keep-mask has exactly k survivors per row and is the SET
-    lax.top_k selects (first occurrence winning ties)."""
+    """The rank image keeps exactly k coordinates per block, the SET
+    lax.top_k selects (first occurrence winning ties), each ranked by
+    its place in lax.top_k's order."""
     B = 128
     x = _rows(kind, seed, 8, B)
-    keep = np.asarray(jax.jit(block_select_mask, static_argnums=1)(x, k))
+    rank = np.asarray(_select(x.T, k)[3]).T
+    keep = rank >= 0
     assert (keep.sum(-1) == k).all()
     _, tidx = lax.top_k(jnp.abs(x), k)
-    expect = np.zeros_like(keep)
-    np.put_along_axis(expect, np.asarray(tidx), True, axis=-1)
-    np.testing.assert_array_equal(keep, expect, kind)
+    expect = np.full(rank.shape, -1)
+    np.put_along_axis(expect, np.asarray(tidx),
+                      np.broadcast_to(np.arange(k), tidx.shape), axis=-1)
+    np.testing.assert_array_equal(rank, expect, kind)
+
+
+@pytest.mark.parametrize("lanes", [1, 8, 128, 136])
+def test_select_blocks_equals_lax_top_k_at_any_lane_count(lanes):
+    """The blocks-on-lanes primitive is lax.top_k per column whatever the
+    number of columns (one block, a partial vreg, whole and ragged lane
+    tiles), on a mix of every corner-case family."""
+    B, k = 256, 8
+    x = jnp.concatenate([_rows(kind, lanes, lanes, B) for kind in KINDS])
+    x = x[jnp.arange(lanes) * len(KINDS) % x.shape[0]]
+    idx, sval, scale, rank = _select(x.T, k)
+    topv, tidx = lax.top_k(jnp.abs(x), k)
+    _bitwise_equal(idx.T, tidx)
+    _bitwise_equal(sval.T, jnp.take_along_axis(x, tidx, -1))
+    _bitwise_equal(scale[0], topv[:, 0])
+    assert (np.asarray(rank >= 0).sum(0) == k).all()
 
 
 def test_block_select_rejects_bad_k():
-    x = jnp.ones((2, 16))
+    x = jnp.ones((16, 2))
     for bad in (0, -1, 17):
         with pytest.raises(ValueError):
-            block_select_mask(x, bad)
+            select_blocks(x, bad)
 
 
 # ---------------------------------------------------------------------------
@@ -115,29 +146,46 @@ def test_fast_pack_bitwise_equals_ref():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("value_dtype", ["float32", "bfloat16"])
-def test_pallas_fused_step_bitwise_equals_ref(value_dtype):
-    """The Pallas kernel (block_select inside the kernel body, interpret
-    mode on CPU) matches the jitted ref oracle bitwise, both wire dtypes,
-    including on tie-heavy input."""
-    n, k, block = 8 * 128, 8, 128
-    g = _rows("ties", 5, n // 128, 128).reshape(-1)
-    e = jax.random.normal(jax.random.PRNGKey(12), (n,)) * 0.1
-    outs_k = ef_topk_fused(g, e, 0.01, 1.0, k, block,
+# block counts: whole tiles, a ragged last grid step, fewer than one tile
+WHOLE, RAGGED, SHORT = TILE_BLOCKS, TILE_BLOCKS + 8, 8
+FUSED_CASES = (
+    [(kind, k, "float32", 1.0, RAGGED) for kind in KINDS for k in (1, 8, 64)]
+    + [("ties", 8, vd, mask, blocks) for vd in ("float32", "bfloat16")
+       for mask in (0.0, 1.0) for blocks in (WHOLE, RAGGED, SHORT)])
+
+
+@pytest.mark.parametrize("kind,k,value_dtype,mask,blocks", FUSED_CASES)
+def test_pallas_fused_step_bitwise_equals_ref(kind, k, value_dtype, mask,
+                                              blocks):
+    """The Pallas kernel (select_blocks inside the kernel body, interpret
+    mode on CPU) matches the jitted ref oracle bit for bit: every corner
+    case family, k from 1 to 64, both wire dtypes, both masks, and block
+    counts that fill, overrun (ragged last grid step) or fall short of
+    one tile."""
+    block = 256
+    g = _rows(kind, k, blocks, block).reshape(-1)
+    e = jax.random.normal(jax.random.PRNGKey(12), g.shape) * 0.1
+    if kind in ("denormal", "zeros", "equal"):
+        e = jnp.zeros_like(e)           # keep acc in the family's corner
+    outs_k = ef_topk_fused(g, e, 1.0, mask, k, block,
                            value_dtype=value_dtype, interpret=True)
     outs_r = jax.jit(lambda a, b: ref.ef_topk_fused_ref(
-        a, b, 0.01, 1.0, k, block, value_dtype=value_dtype))(g, e)
-    for a, b in zip(outs_k, outs_r):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        a, b, 1.0, mask, k, block, value_dtype=value_dtype))(g, e)
+    for name, a, b in zip(("idx", "val", "scale", "c", "e_new"),
+                          outs_k, outs_r):
+        _bitwise_equal(a, b, f"{name} {kind}")
 
 
-def test_pallas_pack_bitwise_equals_ref_on_ties():
-    n, k, block = 8 * 64, 4, 64
-    x = _rows("equal", 9, n // block, block).reshape(-1)
+@pytest.mark.parametrize("kind,blocks", [("equal", SHORT), ("ties", RAGGED),
+                                         ("zeros", RAGGED),
+                                         ("denormal", WHOLE)])
+def test_pallas_pack_bitwise_equals_ref_on_ties(kind, blocks):
+    k, block = 4, 64
+    x = _rows(kind, 9, blocks, block).reshape(-1)
     outs_k = topk_pack(x, k, block, interpret=True)
-    outs_r = ref.topk_pack_ref(x, k, block)
+    outs_r = jax.jit(lambda a: ref.topk_pack_ref(a, k, block))(x)
     for a, b in zip(outs_k, outs_r):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        _bitwise_equal(a, b, kind)
 
 
 def test_bf16_wire_conservation_sterbenz():

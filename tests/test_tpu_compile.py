@@ -4,7 +4,9 @@ Nothing runs: each kernel is lowered with `interpret=False` and compiled
 for a described (not attached) v5e chip, which raises what the chip's
 compiler would raise (an unsupported op, a misaligned tile, too much VMEM).
 The size is the flat vector of `chip_smoke.py`: xlstm-1.3b at its published
-widths cut to one 8-block period, and N=4 senders for the decode kernels.
+widths cut to one 8-block period, and N=4 senders for the decode kernels;
+`topk_pack`, which no train-path cell runs, shares `ef_topk_fused`'s
+selection and tile and is compiled at the same size.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU compiler's library,
@@ -70,6 +72,8 @@ def _kernel_case(name, n, sh):
         return (lambda g, e, a, m: tp.ef_topk_fused(
             g, e, a, m, K, BLOCK, want_c=False, interpret=False),
             (vec, vec, scalar, scalar))
+    if name == "topk_pack":
+        return (lambda x: tp.topk_pack(x, K, BLOCK, interpret=False), (vec,))
     rows = chunk // BLOCK
     return (lambda i, v, s, m: tp.topk_decode_reduce(
         i, v, s, m, BLOCK, interpret=False),
@@ -79,7 +83,8 @@ def _kernel_case(name, n, sh):
 
 
 @pytest.mark.parametrize("kernel", ["ef_sign_fused", "sign_decode_reduce",
-                                    "ef_topk_fused", "topk_decode_reduce"])
+                                    "ef_topk_fused", "topk_decode_reduce",
+                                    "topk_pack"])
 def test_train_path_kernel_compiles_for_v5e(kernel, one_chip, flat_pad):
     fn, args = _kernel_case(kernel, flat_pad, one_chip)
     text = jax.jit(fn).lower(*args).compile().as_text()
