@@ -10,7 +10,7 @@ CONFIG = ModelConfig(
 
 SMOKE = CONFIG.scaled(num_layers=2, d_model=64, num_heads=4, num_kv_heads=4,
                       head_dim=16, d_ff=64, vocab_size=256, moe_experts=8,
-                      moe_top_k=2, moe_ff=64, capacity_factor=4.0)
+                      moe_top_k=2, moe_ff=64)
 
 shapes, skips = lm_shapes(include_long=False)
 

@@ -103,7 +103,6 @@ class TrainRun:
     replan_threshold: float = 0.1    # elastic: max |q_est - q_planned|
     #   before the host recomputes the allocation (epoch bump)
     seed: int = 0
-    aux_weight: float = 0.01
     param_dtype: Optional[str] = None   # override cfg (e.g. "bfloat16")
     metrics: bool = False            # in-graph telemetry (repro.obs): the
     #   train step additionally returns metrics["telemetry"], the reduced
@@ -409,7 +408,7 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
     all_axes = set(mesh.axis_names)
     n_leaves = len(jax.tree.leaves(pshapes))
 
-    def agg_body(params, grads, e, opt, step, key):
+    def agg_body(params, grads, e, opt, step, key, *rows):
         # every op of stage 2 carries "stage2/" in its op_name, and the flat
         # copies (leaves and state to flat vectors and back) carry
         # stage2/flatten or stage2/unflatten; the layout copies the
@@ -441,7 +440,8 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
                     want_norms=True)
                 frame = frame.replace(
                     update_norm_sq=onorms["update_norm_sq"],
-                    param_norm_sq=onorms["param_norm_sq"])
+                    param_norm_sq=onorms["param_norm_sq"],
+                    moe_rows_held=rows[0].reshape(()).astype(jnp.float32))
             else:
                 ghat, e_new = cocoef_update(g_flat, e_loc, None, gamma,
                                             cocoef_cfg, mask_provider=mask_fn,
@@ -467,9 +467,15 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
     params_in_specs = pspecs
     opt_specs = tuple(state_spec for _ in range(n_opt))
 
+    in_specs = (params_in_specs, grads_in_specs, state_spec, opt_specs,
+                P(), P())
     out_specs = (params_in_specs, state_spec, opt_specs)
     if run.metrics:
-        frame_abs = MetricsFrame.abstract(max(n_code, 1), plan.num_buckets)
+        # stage 1's per-coding-rank counters ride in beside the gradients
+        in_specs += (P(lead),)
+        frame_abs = MetricsFrame.abstract(
+            max(n_code, 1), plan.num_buckets).replace(
+            moe_rows_held=jax.ShapeDtypeStruct((), jnp.float32))
         out_specs += (frame_out_specs(frame_abs, mesh.axis_names),)
 
     # check_vma stays off here: the flat vector joins model-sharded and
@@ -478,9 +484,7 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
     # that straddles both kinds of leaf really does differ across model
     # shards).  Every other shard_map in the repo runs with the check on.
     agg = jax.shard_map(
-        agg_body, mesh=mesh,
-        in_specs=(params_in_specs, grads_in_specs, state_spec, opt_specs,
-                  P(), P()),
+        agg_body, mesh=mesh, in_specs=in_specs,
         out_specs=out_specs, axis_names=all_axes, check_vma=False)
 
     # =======================================================================
@@ -518,16 +522,24 @@ def build_train_setup(spec: ArchSpec, mesh: Mesh, shape: ShapeCfg,
             loss, per_ex = model.loss(p, b)
             return loss
 
+        def loss_rows(p, b):
+            loss, _, rows = model.loss(p, b, counters=True)
+            return loss, rows
+
         def grad_one(b):
+            if run.metrics:
+                (l, rows), g = jax.value_and_grad(
+                    lambda p: loss_rows(p, b), has_aux=True)(params)
+                return g, l, rows
             l, g = jax.value_and_grad(lambda p: loss_one(p, b))(params)
             return g, l
 
         with ctx.use_mesh(mesh, weight_gather=weight_gather):
-            grads, losses = jax.vmap(grad_one)(batch)
+            grads, losses, *rows = jax.vmap(grad_one)(batch)
         grads = jax.tree.map(
             lambda x, s: jax.lax.with_sharding_constraint(
                 x, NamedSharding(mesh, s)), grads, gspecs)
-        out = agg(params, grads, e, opt, step, key)
+        out = agg(params, grads, e, opt, step, key, *rows)
         params_new, e_new, opt_new = out[:3]
         metrics = {"loss": losses.mean()}
         if run.metrics:

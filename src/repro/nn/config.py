@@ -24,6 +24,11 @@ class ModelConfig:
     sliding_window: int = 0         # >0: window for local layers
     local_global_period: int = 0    # gemma2: 2 => alternate local/global
     rope_theta: float = 10000.0
+    # YaRN (deepseek-v2's rope_scaling, beta_fast 32, beta_slow 1, mscale
+    # = mscale_all_dim); yarn_factor 0 = plain RoPE
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 4096   # original_max_position_embeddings
+    yarn_mscale: float = 1.0
     norm: str = "rms"               # rms | layer
     mlp: str = "swiglu"             # swiglu | geglu | relu2 | gelu
 
@@ -35,13 +40,15 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # MoE
-    moe_experts: int = 0
+    moe_experts: int = 0            # the router's width
+    moe_experts_held: int = 0       # experts 0..held-1 live here; 0 => all
     moe_top_k: int = 0
+    moe_norm_topk: bool = True      # renormalize the top-k gates to sum 1
+    moe_aux_weight: float = 0.01    # balance-loss coefficient
     moe_shared: int = 0
     moe_ff: int = 0
     moe_first_dense: int = 0        # leading dense layers (deepseek: 1)
     dense_ff: int = 0               # ff of the leading dense layers
-    capacity_factor: float = 1.25
 
     # SSM / hybrid (mamba2 / zamba2)
     ssm_state: int = 0
@@ -70,6 +77,10 @@ class ModelConfig:
             object.__setattr__(self, "d_inner", 2 * self.d_model)
         if self.family in ("ssm", "hybrid") and self.ssm_heads == 0:
             object.__setattr__(self, "ssm_heads", max(1, self.d_inner // 64))
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_experts
 
     @property
     def q_dim(self) -> int:

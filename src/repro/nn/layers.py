@@ -21,6 +21,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .config import ModelConfig
 
@@ -65,17 +66,43 @@ def apply_norm(p, x, cfg: ModelConfig, eps: float = 1e-6):
 # RoPE
 # --------------------------------------------------------------------------
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions broadcastable to (..., seq)."""
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         freqs=None) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions broadcastable to (..., seq).
+    freqs: the half head_dim inverse frequencies (default theta's)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if freqs is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                 / half))
     ang = positions[..., None].astype(jnp.float32) * freqs        # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]                              # (..., S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+YARN_BETA_FAST, YARN_BETA_SLOW = 32, 1   # rotations over the original length
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """YaRN inverse frequencies of `dim` rotary dimensions (deepseek-v2's
+    DeepseekV2YarnRotaryEmbedding): theta's frequencies where a pair turns
+    fast (below pair `low`), theta's over `yarn_factor` where it turns
+    slowly (above `high`), a linear ramp between."""
+    base, f = cfg.rope_theta, cfg.yarn_factor
+
+    def pair(rotations):      # the pair that turns `rotations` times
+        return dim * math.log(cfg.yarn_original_max
+                              / (rotations * 2 * math.pi)) / (2 * math.log(base))
+    low = max(math.floor(pair(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(pair(YARN_BETA_SLOW)), dim - 1)
+    pw = base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (1.0 / (f * pw) * ramp + 1.0 / pw * (1.0 - ramp)).astype(
+        np.float32)
 
 
 # --------------------------------------------------------------------------
@@ -261,6 +288,23 @@ def init_mla(key, cfg: ModelConfig):
     }
 
 
+def _mla_rope(x, positions, cfg: ModelConfig):
+    """RoPE of the decoupled rope dimensions, at YaRN's frequencies where
+    the config sets yarn_factor (cos and sin keep scale 1: mscale equals
+    mscale_all_dim)."""
+    freqs = jnp.asarray(yarn_inv_freq(cfg, cfg.qk_rope_dim)) \
+        if cfg.yarn_factor else None
+    return rope(x, positions, cfg.rope_theta, freqs=freqs)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(qk_nope + qk_rope)^-0.5, times YaRN's (0.1 mscale ln factor + 1)^2."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn_factor > 1:
+        scale *= (0.1 * cfg.yarn_mscale * math.log(cfg.yarn_factor) + 1) ** 2
+    return scale
+
+
 def _mla_latent(p, x, cfg: ModelConfig, positions):
     """Compressed latent [c_kv ; k_rope]: (B, S, r + qk_rope)."""
     ct = x.dtype
@@ -270,7 +314,7 @@ def _mla_latent(p, x, cfg: ModelConfig, positions):
     cf = c.astype(jnp.float32)
     c = (cf * jax.lax.rsqrt((cf ** 2).mean(-1, keepdims=True) + 1e-6)
          * p["kv_norm"].astype(jnp.float32)).astype(ct)
-    k_rope = rope(k_rope[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    k_rope = _mla_rope(k_rope[..., None, :], positions, cfg)[..., 0, :]
     return jnp.concatenate([c, k_rope], axis=-1)
 
 
@@ -279,11 +323,11 @@ def _mla_attend(p, x, lat, cfg: ModelConfig, positions, keep):
     r = cfg.kv_lora_rank
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(ct))
     q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     c_all, krope_all = lat[..., :r], lat[..., r:]
     k_nope = jnp.einsum("btr,rhk->bthk", c_all, p["w_uk"].astype(ct))
     v = jnp.einsum("btr,rhk->bthk", c_all, p["w_uv"].astype(ct))
-    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     scores = (jnp.einsum("bshk,bthk->bsht", q_nope, k_nope)
               + jnp.einsum("bshk,btk->bsht", q_rope, krope_all)) * scale
     scores = jnp.where(keep[:, :, None, :], scores, NEG_INF)
@@ -295,9 +339,10 @@ def _mla_attend(p, x, lat, cfg: ModelConfig, positions, keep):
 def mla_train(p, x, cfg: ModelConfig, return_lat: bool = False):
     B, S, _ = x.shape
     pos = jnp.arange(S)[None]
-    lat = _mla_latent(p, x, cfg, pos)
-    keep = (pos[0][None, :] <= pos[0][:, None])[None]             # (1,S,S)
-    y = _mla_attend(p, x, lat, cfg, pos, keep)
+    with jax.named_scope("mla"):
+        lat = _mla_latent(p, x, cfg, pos)
+        keep = (pos[0][None, :] <= pos[0][:, None])[None]         # (1,S,S)
+        y = _mla_attend(p, x, lat, cfg, pos, keep)
     return (y, lat) if return_lat else y
 
 

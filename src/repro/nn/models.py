@@ -32,8 +32,8 @@ class Model:
         return sum(math.prod(l.shape) for l in jax.tree.leaves(shapes))
 
     # ---- training --------------------------------------------------------
-    def loss(self, params, batch) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        return T.weighted_loss(params, batch, self.cfg)
+    def loss(self, params, batch, counters: bool = False) -> Tuple:
+        return T.weighted_loss(params, batch, self.cfg, counters)
 
     def grad_fn(self):
         def f(params, batch):

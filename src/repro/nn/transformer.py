@@ -161,18 +161,24 @@ def _layer_windows(cfg: ModelConfig, n: int) -> jnp.ndarray:
     return jnp.full((n,), L.BIG_WINDOW)
 
 
-def forward(params, inputs, cfg: ModelConfig):
+def forward(params, inputs, cfg: ModelConfig, counters: bool = False):
     """inputs: tokens (B,S) int32 or embeddings (B,S,d).  Returns (B,S,d)
-    final hidden states (normed) and the scalar MoE aux loss."""
+    final hidden states (normed) and the scalar MoE aux loss; with
+    `counters`, also the rows routed to held experts over the MoE layers."""
     x = L.embed(_gb(params["embed"], cfg), inputs, cfg)
     f = cfg.family
-    aux0 = jnp.zeros((), jnp.float32)
+    # (balance loss, [rows routed to held experts]), summed over layers
+    stats = (jnp.zeros((), jnp.float32),) + (
+        (jnp.zeros((), jnp.int32),) if counters else ())
+
+    def add(stats, more):
+        return tuple(a + b for a, b in zip(stats, more))
 
     if f in ("dense", "moe"):
         windows = _layer_windows(cfg, cfg.num_layers)
 
         def block(carry, scanned):
-            x, aux = carry
+            x, stats = carry
             blk, win = scanned
             blk = _gb(blk, cfg)
             h = L.attn_train(blk["attn"], L.apply_norm(blk["norm1"], x, cfg),
@@ -180,15 +186,15 @@ def forward(params, inputs, cfg: ModelConfig):
             x = x + h
             h2 = L.apply_norm(blk["norm2"], x, cfg)
             if f == "moe":
-                h2, a = MOE.apply_moe(blk["moe"], h2, cfg)
-                aux = aux + a
+                h2, *more = MOE.apply_moe(blk["moe"], h2, cfg, counters)
+                stats = add(stats, more)
             else:
                 h2 = L.apply_mlp(blk["mlp"], h2, cfg)
-            return (_res(x + h2, cfg), aux), None
+            return (_res(x + h2, cfg), stats), None
 
         x = _res(x, cfg)
-        (x, aux0), _ = jax.lax.scan(_maybe_remat(block, cfg), (x, aux0),
-                                    (params["blocks"], windows))
+        (x, stats), _ = jax.lax.scan(_maybe_remat(block, cfg), (x, stats),
+                                     (params["blocks"], windows))
 
     elif f == "deepseek":
         b0 = _gb(params["block0"], cfg)
@@ -196,17 +202,18 @@ def forward(params, inputs, cfg: ModelConfig):
         x = x + L.apply_mlp(b0["mlp"], L.apply_norm(b0["norm2"], x, cfg), cfg)
 
         def block(carry, blk):
-            x, aux = carry
+            x, stats = carry
             blk = _gb(blk, cfg)
             x = x + L.mla_train(blk["attn"],
                                 L.apply_norm(blk["norm1"], x, cfg), cfg)
-            h, a = MOE.apply_moe(blk["moe"],
-                                 L.apply_norm(blk["norm2"], x, cfg), cfg)
-            return (_res(x + h, cfg), aux + a), None
+            h, *more = MOE.apply_moe(blk["moe"],
+                                     L.apply_norm(blk["norm2"], x, cfg), cfg,
+                                     counters)
+            return (_res(x + h, cfg), add(stats, more)), None
 
         x = _res(x, cfg)
-        (x, aux0), _ = jax.lax.scan(_maybe_remat(block, cfg), (x, aux0),
-                                    params["blocks"])
+        (x, stats), _ = jax.lax.scan(_maybe_remat(block, cfg), (x, stats),
+                                     params["blocks"])
 
     elif f == "hybrid":
         def mamba_block(x, blk):
@@ -240,12 +247,14 @@ def forward(params, inputs, cfg: ModelConfig):
     else:
         raise ValueError(f)
 
-    return L.apply_norm(params["final_norm"], x, cfg), aux0
+    return (L.apply_norm(params["final_norm"], x, cfg),) + stats
 
 
 def weighted_loss(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
-                  aux_weight: float = 0.01):
-    """Coded training loss: sum_j w_j * mean-token-NLL(example j).
+                  counters: bool = False):
+    """Coded training loss: sum_j w_j * mean-token-NLL(example j), plus
+    `moe_aux_weight` times the MoE balance loss.  Returns (loss,
+    per-example NLL), and with `counters` the rows routed to held experts.
 
     batch: {"inputs": tokens (B,S+1) or embeddings (B,S,d),
             "targets": (B,S) int32 (embeddings mode only),
@@ -257,13 +266,13 @@ def weighted_loss(params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
     else:
         inputs = batch["inputs"]
         targets = batch["targets"]
-    x, aux = forward(params, inputs, cfg)
+    x, aux, *rows = forward(params, inputs, cfg, counters)
     logits = L.logits_from(_gb(params["embed"], cfg), x, cfg)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
     per_example = nll.mean(axis=-1)                       # (B,)
     loss = (per_example * batch["weights"]).sum()
-    return loss + aux_weight * aux, per_example
+    return (loss + cfg.moe_aux_weight * aux, per_example) + tuple(rows)
 
 
 # ==========================================================================

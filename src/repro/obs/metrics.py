@@ -24,6 +24,11 @@ jit/shard_map scope (no host callbacks, no extra collectives) by
   ghat_norm_sq      ()    |ghat_local|^2 of the aggregated update slice
   update_norm_sq    ()    |theta_new - theta|^2 (optimizer, incl. decay)
   param_norm_sq     ()    |theta_new|^2
+  moe_rows_held     ()    rows stage 1 routed to the experts held on this
+                          device, summed over the MoE layers (its coding
+                          rank's; 0 for a model without experts).  Filled
+                          by the train step; None in a frame that
+                          `cocoef_update` returns alone
 
 Scalar leaves are DEVICE-LOCAL partial sums over that device's slice of
 the flat vector; `reduce_frame_grid` turns the (mesh-grid)-shaped output
@@ -68,6 +73,7 @@ class MetricsFrame:
     ghat_norm_sq: jnp.ndarray         # ()  f32
     update_norm_sq: jnp.ndarray       # ()  f32
     param_norm_sq: jnp.ndarray        # ()  f32
+    moe_rows_held: jnp.ndarray = None  # ()  f32
 
     def replace(self, **kw) -> "MetricsFrame":
         return dataclasses.replace(self, **kw)
@@ -104,7 +110,7 @@ jax.tree_util.register_dataclass(
 _CORNER = ("participation", "wire_bytes_rank", "bytes_down")
 _RANK_SUM = ("grad_norm_sq", "ef_norm_sq", "acc_norm_sq", "c_norm_sq",
              "acc_dot_c")
-_RANK_VEC = ("bucket_wire_bytes",)
+_RANK_VEC = ("bucket_wire_bytes", "moe_rows_held")
 _REPL_MEAN = ("ghat_norm_sq", "update_norm_sq", "param_norm_sq")
 
 
@@ -146,8 +152,9 @@ def reduce_frame_grid(frame: MetricsFrame, mesh_axis_names: Sequence[str],
         t = t.sum(axis=tuple(range(len(code_pos), m)))
         return t.reshape(-1)
 
-    def rank_vec(leaf):                       # (mesh..., k) -> (N, k)
-        t = jnp.transpose(leaf, code_pos + other_pos + [m])
+    def rank_vec(leaf):                       # (mesh..., *k) -> (N, *k)
+        t = jnp.transpose(leaf, code_pos + other_pos
+                          + list(range(m, leaf.ndim)))
         t = t[(slice(None),) * len(code_pos) + (0,) * len(other_pos)]
         return t.reshape((-1,) + leaf.shape[m:])
 
@@ -182,6 +189,8 @@ def reduce_frame_grid(frame: MetricsFrame, mesh_axis_names: Sequence[str],
         "update_norm": jnp.sqrt(repl_mean(frame.update_norm_sq)),
         "param_norm": jnp.sqrt(repl_mean(frame.param_norm_sq)),
     }
+    if frame.moe_rows_held is not None:
+        out["moe_rows_held_rank"] = rank_vec(frame.moe_rows_held)
     return out
 
 

@@ -46,7 +46,7 @@ _RULES: Dict[str, Tuple[tuple, tuple]] = {
     "w_gate": ((None, M), ((D,), M)),
     "w_up": ((None, M), ((D,), M)),
     "w_down": ((M, None), (M, (D,))),
-    # MoE experts (leading expert dim -> EP over model)
+    # MoE experts held here (leading dim experts_held -> EP over model)
     "w_gate_e": ((M, None, None), (M, (D,), None)),
     "w_up_e": ((M, None, None), (M, (D,), None)),
     "w_down_e": ((M, None, None), (M, None, (D,))),

@@ -690,3 +690,65 @@ def test_shard_map_per_rank_metrics_match_ledger():
                                    err_msg=name)
         print(name, "OK")
     """)
+
+
+def test_moe_rows_counter_rides_with_metrics_only():
+    """`moe_rows_held`: with metrics on, the step reports per coding rank
+    the rows its stage 1 routed to the held experts over the MoE layers
+    (what the model's loss counts for that rank's batch); with metrics
+    off the counter is never traced, so the step lowers to the same HLO
+    as a model that cannot count."""
+    run_sub("""
+    import dataclasses
+    from repro.configs import REGISTRY
+    from repro.configs.common import ShapeCfg
+    from repro.launch.train import (TrainRun, build_train_setup,
+                                    make_batch_for_step)
+    from repro.nn import moe as MOE
+
+    arch = REGISTRY["deepseek-v2-lite-16b"]
+    spec = dataclasses.replace(
+        arch, smoke=arch.smoke.scaled(moe_experts_held=4),
+        coding=dataclasses.replace(arch.coding, straggler_p=0.0))
+    cfg = spec.smoke
+    mesh = make_mesh((2, 2), ("data", "model"))
+    shape = ShapeCfg("train", 16, 4)
+
+    def lowered(metrics):
+        s = build_train_setup(spec, mesh, shape,
+                              TrainRun(base_lr=1e-2, metrics=metrics,
+                                       backend="jnp"), smoke=True)
+        sp = s.input_specs()
+        return s, jax.jit(s.train_step).lower(
+            sp["params"], sp["e"], sp["opt"], sp["batch"], sp["step"],
+            sp["key"])
+
+    _, off = lowered(False)
+    orig = MOE.apply_moe
+
+    def no_counters(p, x, c, counters=False):
+        assert not counters, "metrics off traced the counter"
+        return orig(p, x, c)
+    MOE.apply_moe = no_counters
+    try:
+        _, bare = lowered(False)
+    finally:
+        MOE.apply_moe = orig
+    assert off.as_text() == bare.as_text()
+
+    s, _ = lowered(True)
+    params, e, opt = s.init_state(jax.random.PRNGKey(0))
+    batch = make_batch_for_step(s, spec, shape, jax.random.PRNGKey(1), 0,
+                                smoke=True)
+    *_, m = jax.jit(s.train_step)(params, e, opt, batch, jnp.int32(0),
+                                  jax.random.PRNGKey(2))
+    got = np.asarray(m["telemetry"]["moe_rows_held_rank"])
+    assert got.shape == (2,)
+    for r in range(2):
+        b = jax.tree.map(lambda a: a[r], batch)
+        want = int(s.model.loss(params, b, counters=True)[2])
+        assert got[r] == want, (r, got, want)
+        bound = (cfg.num_layers - 1) * b["inputs"].shape[0] * 16 * min(
+            cfg.moe_top_k, 4)
+        assert 0 < want <= bound
+    """, devices=4)

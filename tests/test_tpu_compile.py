@@ -6,13 +6,19 @@ compiler would raise (an unsupported op, a misaligned tile, too much VMEM).
 The size is the flat vector of `chip_smoke.py`: xlstm-1.3b at its published
 widths cut to one 8-block period, and N=4 senders for the decode kernels;
 `topk_pack`, which no train-path cell runs, shares `ef_topk_fused`'s
-selection and tile and is compiled at the same size.
+selection and tile and is compiled at the same size.  The whole train
+step of the deepseek-v2-lite cell (bench/configs/deepseek-v2-lite-l5e8.json:
+MLA, the dropless expert layer's grouped matmuls, the sign wire) is
+compiled at its real size too, as bench/program.py builds it.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU compiler's library,
 and every test worker imports every test file.
 """
+import dataclasses
+import json
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +44,11 @@ def one_chip():
     except Exception as e:  # no TPU compiler here: nothing to check against
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def topo_devices(one_chip):
+    return list(one_chip.device_set)
 
 
 @pytest.fixture(scope="module")
@@ -90,3 +101,39 @@ def test_train_path_kernel_compiles_for_v5e(kernel, one_chip, flat_pad):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert 'custom_call_target="tpu_custom_call"' in text, kernel
     assert f"%{kernel}" in text, f"{kernel} is not the Mosaic call"
+
+
+def test_deepseek_train_step_compiles_for_v5e(topo_devices, monkeypatch):
+    from repro.compat import make_mesh
+    from repro.configs.common import ShapeCfg
+    from repro.core.plan import PlanSpec
+    from repro.kernels import ops
+    from repro.launch.train import TrainRun, build_train_setup
+    root = Path(__file__).resolve().parents[1]
+    model = json.loads((root / "bench/configs/deepseek-v2-lite-l5e8.json")
+                       .read_text())["model"]
+    traffic = json.loads((root / "bench/traffic/sign.json").read_text())
+    # code that asks for the default backend sees the CPU here
+    monkeypatch.setattr(ops, "default_use_pallas", lambda: True)
+    arch = REGISTRY["deepseek-v2-lite-16b"]
+    spec = dataclasses.replace(
+        arch, config=dataclasses.replace(arch.config, **model),
+        coding=dataclasses.replace(arch.coding, straggler_p=0.0))
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo_devices[:1])
+    plan = PlanSpec(d=1, compressor="sign", group_size=GROUP,
+                    backend="pallas")
+    setup = build_train_setup(
+        spec, mesh, ShapeCfg("train", traffic["seq_len"],
+                             traffic["rows_per_chip"]),
+        TrainRun(mode="cocoef", base_lr=traffic["lr"], plan=plan))
+    specs = setup.input_specs()
+    compiled = jax.jit(setup.train_step, donate_argnums=(0, 1)).lower(
+        specs["params"], specs["e"], specs["opt"], specs["batch"],
+        specs["step"], specs["key"]).compile()
+    text = compiled.as_text()
+    for kernel in traffic["kernels"]:
+        assert f"%{kernel}" in text, f"{kernel} is not a Mosaic call"
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < 16e9, used
