@@ -53,9 +53,11 @@ _fallback_warned = set()
 def resolve_use_pallas(use_pallas, n: int, tile_elems: int, op: str = "",
                        dtype=None) -> bool:
     """Concrete kernel choice for a flat length `n`: the tristate
-    `use_pallas` (None = Pallas iff on TPU) guarded by the kernel's row
-    tile — shapes not divisible by `tile_elems` (G_BLK/R_BLK rows worth of
-    elements) fall back to the jnp path, which has no tile.
+    `use_pallas` (None = Pallas iff on TPU) guarded by the wire's row
+    alignment — shapes not divisible by `tile_elems` (G_BLK/R_BLK rows
+    worth of elements, the `pad_multiple` the train path pads to; not the
+    kernels' grid tile, whose last step may be ragged) fall back to the
+    jnp path, which has no alignment.
 
     When Pallas was EXPLICITLY requested (`use_pallas=True`, i.e.
     backend="pallas") and the tile guard rejects the shape, warn once per

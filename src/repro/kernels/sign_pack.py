@@ -5,14 +5,44 @@ rank makes one pass over its model-sized accumulator to (a) compress it to
 the wire format and (b) update the error vector.  Fusing the whole local
 step (ef_sign_fused) turns three HBM round-trips (acc, C(acc), e') into one.
 
-Tiling: the flat vector is processed as (rows of ROW_GROUPS groups) x
-(group_size lanes).  group_size is a multiple of 32 (bit-pack word); the
-production group (512) is also a multiple of the 128-lane vreg width:
+Tiling: the flat vector is viewed as (groups, group_size); group_size is a
+multiple of 32 (bit-pack word) and the production group (512) a multiple
+of the 128-lane vreg width.  A grid step takes T =
+`topk_block.tile_blocks(groups, TILE_GROUPS)` groups (512 at the train
+path's size; the last step may be ragged), and the payload runs
+lane-dense, one group per lane:
 
-  x block      (G_BLK, group)            f32   VMEM
-  words block  (G_BLK, group // 32)      i32   VMEM  (u32 bit pattern)
-  scales block (G_BLK, 1)                f32   VMEM
-  gamma / mask                           f32   SMEM  (scalars)
+  sign_pack, ef_sign_fused
+    x, g, e blocks   (T, group)          f32  VMEM
+    words block      (group // 32, T)    i32  VMEM, lane-dense (u32 bits)
+    scales block     (1, T)              f32  VMEM, lane-dense
+    c, e' blocks     (T, group)          f32  VMEM
+    gamma / mask                         f32  SMEM  (scalars)
+  sign_decode_reduce
+    words block      (N, group // 32, T) i32  VMEM, lane-dense
+    scales block     (N, 1, T)           f32  VMEM, lane-dense
+    out block        (T, group)          f32  VMEM, built as (group, T)
+                                              and transposed once
+    mask                                 f32  SMEM
+
+The wrappers take and hand back the payload in wire order, (groups,
+group // 32) flattened; the transposes to and from the lane-dense blocks
+are XLA ops outside the kernels, and where only reshapes lie between
+`ef_sign_fused` and `sign_decode_reduce` (one rank) the compiled step
+drops the pair.  G_BLK groups is the wire's row alignment
+(`CocoEFConfig.pad_multiple`, `SignWire._tile`), not the grid tile.
+
+Why: with 8 groups a grid step, each step moved about 56 KB in a fixed
+cost of about 0.3 us, and the words block (8, 16) and the scales block
+(8, 1) sat in (8,128)-tiled buffers, 8x and 128x their size.  On a TPU
+v5e, for the 992,816 groups of xlstm-1.3b's 508M-coordinate flat vector,
+`ef_sign_fused` took 42.8 ms a call, 0.344 us per grid step of 8 groups,
+and `sign_decode_reduce` (one sender) 39.4 ms, 0.317 us.  At T = 512 they
+take 9.1 ms (4.67 us per grid step, 83% of the bytes roofline) and 3.1 ms
+(1.59 us), every output bit for bit the old kernels'.  T = 256 /
+512 / 1024 read 9.22 / 9.05 / 8.99 ms and 3.10 / 3.09 / 3.09 ms: past a
+few hundred groups the kernels are bound by HBM, not by the grid.
+T = 1024 does not fit Mosaic's default scoped VMEM with `want_c`.
 
 Mosaic has no unsigned reductions, no uint32 -> f32 cast and no
 lane-splitting reshape, so the kernels work in int32 and the wrappers
@@ -20,10 +50,12 @@ bitcast to the uint32 wire words outside the kernel (free in XLA):
 
   pack    bit j of word w is (x[32w + j] >= 0).  The 0/1 bits are summed
           against power-of-two weights on the MXU, one 16-bit half of the
-          word per matmul: every product and partial sum is an integer
-          below 2**16, exact in bf16 x bf16 -> f32, so the word is exactly
-          the one `kernels.ref.sign_pack_ref` builds.
-  unpack  int32 logical shifts and masks, then int32 -> f32.
+          word per matmul, which contracts each group's lanes and leaves
+          the words of the group on the lanes: every product and partial
+          sum is an integer below 2**16, exact in bf16 x bf16 -> f32, so
+          the word is exactly the one `kernels.ref.sign_pack_ref` builds.
+  unpack  for word w, rows 32w..32w+31 of the (group, T) image are its
+          bits, int32 logical shifts down the sublanes; then int32 -> f32.
 
 Off the TPU the same pallas_call runs with interpret=True (pure-JAX
 semantics) and is validated bit-for-bit against kernels/ref.py.
@@ -41,17 +73,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import vary_alike
+from repro.kernels.topk_block import tile_blocks
 
-G_BLK = 8  # groups per grid step
+G_BLK = 8  # the sign wire's row alignment in groups (pad_multiple)
+TILE_GROUPS = 512  # groups per grid step of the sign kernels
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole (k,) f32 scalar array
+_NT = (((1,), (1,)), ((), ()))                   # contract both minor dims
+
+
+def _groups(n: int, group_size: int, op: str) -> int:
+    if n % (G_BLK * group_size):
+        raise ValueError(f"{op} needs n % (G_BLK*group_size) == 0, got "
+                         f"n={n}, G_BLK={G_BLK}, group_size={group_size}")
+    return n // group_size
 
 
 def _pack_weights(group_size: int) -> jnp.ndarray:
-    """(2, group, group//32) bf16: [0] weights lane l by 2**(l % 32) into
+    """(2, group//32, group) bf16: [0] weights lane l by 2**(l % 32) into
     word l // 32 for bits 0..15, [1] by 2**(l % 32 - 16) for bits 16..31."""
-    lane = np.arange(group_size)[:, None]
-    word = np.arange(group_size // 32)[None, :]
+    word = np.arange(group_size // 32)[:, None]
+    lane = np.arange(group_size)[None, :]
     bit = lane % 32
     own = lane // 32 == word
     halves = [np.where(own & (bit // 16 == h), 2.0 ** (bit % 16), 0.0)
@@ -61,57 +103,59 @@ def _pack_weights(group_size: int) -> jnp.ndarray:
 
 def _pack_spec(group_size: int) -> pl.BlockSpec:
     # constant block index: the weights are fetched once and stay in VMEM
-    return pl.BlockSpec((2, group_size, group_size // 32),
+    return pl.BlockSpec((2, group_size // 32, group_size),
                         lambda i: (0, 0, 0))
 
 
+def _payload(group_size: int, ng: int, tile: int, sds):
+    """Lane-dense (group//32, ng) words and (1, ng) scales: specs, shapes."""
+    specs = [pl.BlockSpec((group_size // 32, tile), lambda i: (0, i)),
+             pl.BlockSpec((1, tile), lambda i: (0, i))]
+    shapes = [sds((group_size // 32, ng), jnp.int32),
+              sds((1, ng), jnp.float32)]
+    return specs, shapes
+
+
 def _pack_block(x_blk, pw_ref):
-    """x_blk: (G_BLK, group) f32 -> (words (G_BLK, group//32) i32 holding
-    the u32 bit pattern, scales (G_BLK, 1) f32)."""
-    scales = jnp.mean(jnp.abs(x_blk), axis=-1, keepdims=True)     # (G,1)
+    """x_blk: (T, group) f32 -> (words (group//32, T) i32 holding the u32
+    bit pattern, scales (T, 1) f32)."""
+    scales = jnp.mean(jnp.abs(x_blk), axis=-1, keepdims=True)     # (T,1)
     bits = (x_blk >= 0).astype(jnp.bfloat16)
-    lo, hi = (jnp.dot(bits, pw_ref[h], preferred_element_type=jnp.float32)
+    lo, hi = (lax.dot_general(pw_ref[h], bits, _NT,
+                              preferred_element_type=jnp.float32)
               .astype(jnp.int32) for h in (0, 1))
     return lo | (hi << 16), scales
 
 
 def _as_u32(words: jnp.ndarray) -> jnp.ndarray:
-    return lax.bitcast_convert_type(words, jnp.uint32).reshape(-1)
+    """Lane-dense (group//32, ng) words -> the (n/32,) u32 wire words."""
+    return lax.bitcast_convert_type(words.T, jnp.uint32).reshape(-1)
 
 
 def _sign_pack_kernel(x_ref, pw_ref, words_ref, scales_ref):
     words, scales = _pack_block(x_ref[...].astype(jnp.float32), pw_ref)
     words_ref[...] = words
-    scales_ref[...] = scales
+    scales_ref[...] = scales.T
 
 
 @functools.partial(jax.jit, static_argnames=("group_size", "interpret"))
 def sign_pack(x: jnp.ndarray, group_size: int, interpret: bool = True
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (n,) f32, n % (G_BLK * group_size) == 0."""
-    n = x.shape[0]
-    if n % (G_BLK * group_size):
-        raise ValueError(f"sign_pack needs n % (G_BLK*group_size) == 0, got "
-                         f"n={n}, G_BLK={G_BLK}, group_size={group_size}")
-    ng = n // group_size
-    grid = (ng // G_BLK,)
+    ng = _groups(x.shape[0], group_size, "sign_pack")
+    tile = tile_blocks(ng, TILE_GROUPS)
     args, axes = vary_alike(x.reshape(ng, group_size),
                             _pack_weights(group_size))
     sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
+    out_specs, out_shape = _payload(group_size, ng, tile, sds)
     words, scales = pl.pallas_call(
         _sign_pack_kernel,
         name="sign_pack",
-        grid=grid,
-        in_specs=[pl.BlockSpec((G_BLK, group_size), lambda i: (i, 0)),
+        grid=(pl.cdiv(ng, tile),),
+        in_specs=[pl.BlockSpec((tile, group_size), lambda i: (i, 0)),
                   _pack_spec(group_size)],
-        out_specs=[
-            pl.BlockSpec((G_BLK, group_size // 32), lambda i: (i, 0)),
-            pl.BlockSpec((G_BLK, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            sds((ng, group_size // 32), jnp.int32),
-            sds((ng, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(*args)
     return _as_u32(words), scales.reshape(-1)
@@ -123,9 +167,9 @@ def _ef_fused_kernel(g_ref, e_ref, gamma_ref, mask_ref, pw_ref,
     mask = mask_ref[0]
     acc = gamma * g_ref[...].astype(jnp.float32) + e_ref[...].astype(jnp.float32)
     words, scales = _pack_block(acc, pw_ref)
-    c = (jnp.where(acc >= 0, 1.0, -1.0) * scales)                  # (G, group)
+    c = (jnp.where(acc >= 0, 1.0, -1.0) * scales)                  # (T, group)
     words_ref[...] = words
-    scales_ref[...] = scales
+    scales_ref[...] = scales.T
     if want_c:
         out_refs[0][...] = c
     out_refs[-1][...] = jnp.where(mask > 0, acc - c,
@@ -142,39 +186,24 @@ def ef_sign_fused(g: jnp.ndarray, e: jnp.ndarray, gamma, mask_self,
     g, e: (n,) f32; gamma, mask_self: scalars.  want_c=False skips the
     full-vector c store (the train path only ships the payload; a custom
     call's outputs are not DCE-able, so the skip must be explicit)."""
-    n = g.shape[0]
-    if n % (G_BLK * group_size):
-        raise ValueError(f"ef_sign_fused needs n % (G_BLK*group_size) == 0, "
-                         f"got n={n}, G_BLK={G_BLK}, group_size={group_size}")
-    ng = n // group_size
-    grid = (ng // G_BLK,)
+    ng = _groups(g.shape[0], group_size, "ef_sign_fused")
+    tile = tile_blocks(ng, TILE_GROUPS)
     args, axes = vary_alike(
         g.reshape(ng, group_size), e.reshape(ng, group_size),
         jnp.asarray(gamma, jnp.float32).reshape(1),
         jnp.asarray(mask_self, jnp.float32).reshape(1),
         _pack_weights(group_size))
     sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
-    full = [pl.BlockSpec((G_BLK, group_size), lambda i: (i, 0)),
-            sds((ng, group_size), jnp.float32)]
+    full = pl.BlockSpec((tile, group_size), lambda i: (i, 0))
+    out_specs, out_shape = _payload(group_size, ng, tile, sds)
     outs = pl.pallas_call(
         functools.partial(_ef_fused_kernel, want_c=want_c),
         name="ef_sign_fused",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((G_BLK, group_size), lambda i: (i, 0)),
-            pl.BlockSpec((G_BLK, group_size), lambda i: (i, 0)),
-            _SMEM,
-            _SMEM,
-            _pack_spec(group_size),
-        ],
-        out_specs=[
-            pl.BlockSpec((G_BLK, group_size // 32), lambda i: (i, 0)),
-            pl.BlockSpec((G_BLK, 1), lambda i: (i, 0)),
-        ] + [full[0]] * (1 + want_c),
-        out_shape=[
-            sds((ng, group_size // 32), jnp.int32),
-            sds((ng, 1), jnp.float32),
-        ] + [full[1]] * (1 + want_c),
+        grid=(pl.cdiv(ng, tile),),
+        in_specs=[full, full, _SMEM, _SMEM, _pack_spec(group_size)],
+        out_specs=out_specs + [full] * (1 + want_c),
+        out_shape=out_shape + [sds((ng, group_size), jnp.float32)]
+        * (1 + want_c),
         interpret=interpret,
     )(*args)
     words, scales = outs[0], outs[1]
@@ -183,16 +212,15 @@ def ef_sign_fused(g: jnp.ndarray, e: jnp.ndarray, gamma, mask_self,
 
 
 def _decode_reduce_kernel(words_ref, scales_ref, mask_ref, out_ref,
-                          *, group_size: int, n_senders: int):
-    acc = jnp.zeros(out_ref.shape, jnp.float32)                    # (G, group)
+                          *, n_senders: int):
+    nw, tile = words_ref.shape[1:]
+    shift = lax.broadcasted_iota(jnp.int32, (nw, 32, tile), 1)
+    acc = jnp.zeros((nw * 32, tile), jnp.float32)                  # (group, T)
     for i in range(n_senders):                                     # static loop
-        w = words_ref[i]                                           # (G, g/32)
-        s = scales_ref[i]                                          # (G, 1)
-        shift = lax.broadcasted_iota(jnp.int32, w.shape + (32,), 2)
-        bits = lax.shift_right_logical(w[..., None], shift) & 1
-        signs = bits.reshape(out_ref.shape).astype(jnp.float32) * 2.0 - 1.0
-        acc = acc + mask_ref[i] * signs * s
-    out_ref[...] = acc
+        bits = lax.shift_right_logical(words_ref[i][:, None, :], shift) & 1
+        signs = bits.reshape(acc.shape).astype(jnp.float32) * 2.0 - 1.0
+        acc = acc + mask_ref[i] * signs * scales_ref[i]
+    out_ref[...] = acc.T
 
 
 @functools.partial(jax.jit, static_argnames=("group_size", "interpret"))
@@ -202,29 +230,24 @@ def sign_decode_reduce(words: jnp.ndarray, scales: jnp.ndarray,
     """Server-side decode + masked aggregate.
     words: (N, n/32) u32; scales: (N, n/g) f32; mask: (N,) f32 -> (n,)."""
     N = words.shape[0]
-    n = words.shape[1] * 32
-    if n % (G_BLK * group_size):
-        raise ValueError(f"sign_decode_reduce needs n % (G_BLK*group_size) "
-                         f"== 0, got n={n}, G_BLK={G_BLK}, "
-                         f"group_size={group_size}")
-    ng = n // group_size
-    grid = (ng // G_BLK,)
+    ng = _groups(words.shape[1] * 32, group_size, "sign_decode_reduce")
+    tile = tile_blocks(ng, TILE_GROUPS)
+    nw = group_size // 32
     args, axes = vary_alike(
-        lax.bitcast_convert_type(words, jnp.int32).reshape(
-            N, ng, group_size // 32),
-        scales.reshape(N, ng, 1), mask.astype(jnp.float32))
+        jnp.swapaxes(lax.bitcast_convert_type(words, jnp.int32).reshape(
+            N, ng, nw), 1, 2),
+        scales.reshape(N, 1, ng), mask.astype(jnp.float32))
     sds = functools.partial(jax.ShapeDtypeStruct, vma=axes)
     out = pl.pallas_call(
-        functools.partial(_decode_reduce_kernel, group_size=group_size,
-                          n_senders=N),
+        functools.partial(_decode_reduce_kernel, n_senders=N),
         name="sign_decode_reduce",
-        grid=grid,
+        grid=(pl.cdiv(ng, tile),),
         in_specs=[
-            pl.BlockSpec((N, G_BLK, group_size // 32), lambda i: (0, i, 0)),
-            pl.BlockSpec((N, G_BLK, 1), lambda i: (0, i, 0)),
+            pl.BlockSpec((N, nw, tile), lambda i: (0, 0, i)),
+            pl.BlockSpec((N, 1, tile), lambda i: (0, 0, i)),
             _SMEM,
         ],
-        out_specs=pl.BlockSpec((G_BLK, group_size), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((tile, group_size), lambda i: (i, 0)),
         out_shape=sds((ng, group_size), jnp.float32),
         interpret=interpret,
     )(*args)

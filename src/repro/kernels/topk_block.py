@@ -46,14 +46,15 @@ from repro.compat import vary_alike
 TILE_BLOCKS = 1024  # blocks (lanes) per grid step of the selection kernels
 
 
-def tile_blocks(rows: int) -> int:
-    """Blocks per grid step for `rows` blocks: TILE_BLOCKS, clipped to the
-    largest multiple of 128 that fits, or to `rows` itself below 128 (a
-    block as wide as the array needs no lane alignment).  The last grid
-    step may be ragged: its out-of-range lanes are never written back."""
+def tile_blocks(rows: int, most: int = TILE_BLOCKS) -> int:
+    """Blocks per grid step for `rows` blocks: `most` (a multiple of 128),
+    clipped to the largest multiple of 128 that fits, or to `rows` itself
+    below 128 (a block as wide as the array needs no lane alignment).  The
+    last grid step may be ragged: its out-of-range lanes are never written
+    back."""
     if rows < 128:
         return rows
-    return min(TILE_BLOCKS, rows - rows % 128)
+    return min(most, rows - rows % 128)
 
 
 def _sum(x):

@@ -6,9 +6,14 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.sign_pack import ef_sign_fused, sign_decode_reduce, \
-    sign_pack
+from repro.kernels.sign_pack import TILE_GROUPS, ef_sign_fused, \
+    sign_decode_reduce, sign_pack
 from repro.kernels.topk_block import block_topk
+
+# group counts: whole grid tiles, one tile plus 8 groups (a ragged last
+# grid step), fewer groups than one tile
+WHOLE, RAGGED, SHORT = 2 * TILE_GROUPS, TILE_GROUPS + 8, 8
+TILE_CASES = {"whole": WHOLE, "ragged": RAGGED, "short": SHORT}
 
 
 @pytest.mark.parametrize("group", [128, 256, 512])
@@ -77,6 +82,54 @@ def test_sign_decode_reduce(n_senders):
     words = jnp.stack(ws)
     scales = jnp.stack(ss)
     mask = (jnp.arange(n_senders) % 2).astype(jnp.float32)
+    out_k = sign_decode_reduce(words, scales, mask, g, interpret=True)
+    out_r = ref.sign_decode_reduce_ref(words, scales, mask, g)
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("groups", TILE_CASES.values(), ids=TILE_CASES)
+def test_sign_pack_tiles(groups):
+    """The words are sign_pack_ref's bit for bit whether the group count
+    fills whole grid tiles, leaves a ragged last grid step or falls short
+    of one tile."""
+    g = 512
+    x = jax.random.normal(jax.random.PRNGKey(groups), (groups * g,))
+    w1, s1 = sign_pack(x, g, interpret=True)
+    w2, s2 = ref.sign_pack_ref(x, g)
+    np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-6)
+
+
+@pytest.mark.parametrize("groups", TILE_CASES.values(), ids=TILE_CASES)
+@pytest.mark.parametrize("want_c", [True, False])
+@pytest.mark.parametrize("mask", [0.0, 1.0])
+def test_ef_fused_tiles(groups, want_c, mask):
+    g = 512
+    gv = jax.random.normal(jax.random.PRNGKey(groups + 1), (groups * g,))
+    e = jax.random.normal(jax.random.PRNGKey(groups + 2), gv.shape) * 0.1
+    outs_k = ef_sign_fused(gv, e, 0.01, mask, g, want_c=want_c,
+                           interpret=True)
+    outs_r = ref.ef_sign_fused_ref(gv, e, 0.01, mask, g)
+    assert (outs_k[2] is None) == (not want_c)
+    np.testing.assert_array_equal(np.asarray(outs_k[0]),
+                                  np.asarray(outs_r[0]))
+    for a, b in zip(outs_k[1:], outs_r[1:]):
+        if a is not None:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("groups", TILE_CASES.values(), ids=TILE_CASES)
+@pytest.mark.parametrize("n_senders", [1, 4])
+def test_sign_decode_reduce_tiles(groups, n_senders):
+    g = 512
+    packs = [ref.sign_pack_ref(jax.random.normal(
+        jax.random.PRNGKey(groups + i), (groups * g,)), g)
+        for i in range(n_senders)]
+    words = jnp.stack([w for w, _ in packs])
+    scales = jnp.stack([s for _, s in packs])
+    mask = (jnp.arange(n_senders) % 3 != 1).astype(jnp.float32)
     out_k = sign_decode_reduce(words, scales, mask, g, interpret=True)
     out_r = ref.sign_decode_reduce_ref(words, scales, mask, g)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),

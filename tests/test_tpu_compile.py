@@ -6,7 +6,9 @@ compiler would raise (an unsupported op, a misaligned tile, too much VMEM).
 The size is the flat vector of `chip_smoke.py`: xlstm-1.3b at its published
 widths cut to one 8-block period, and N=4 senders for the decode kernels;
 `topk_pack`, which no train-path cell runs, shares `ef_topk_fused`'s
-selection and tile and is compiled at the same size.  The whole train
+selection and tile and is compiled at the same size.  The sign kernels
+are compiled at the deepseek-v2-lite cell's flat size too (one sender,
+d=1); both sizes leave them a ragged last grid step.  The whole train
 step of the deepseek-v2-lite cell (bench/configs/deepseek-v2-lite-l5e8.json:
 MLA, the dropless expert layer's grouped matmuls, the sign wire) is
 compiled at its real size too, as bench/program.py builds it.
@@ -51,25 +53,46 @@ def topo_devices(one_chip):
     return list(one_chip.device_set)
 
 
+def _n_params(cfg) -> int:
+    return sum(l.size for l in jax.tree.leaves(Model(cfg).param_shapes()))
+
+
+def _deepseek_spec():
+    root = Path(__file__).resolve().parents[1]
+    model = json.loads((root / "bench/configs/deepseek-v2-lite-l5e8.json")
+                       .read_text())["model"]
+    arch = REGISTRY["deepseek-v2-lite-16b"]
+    return dataclasses.replace(
+        arch, config=dataclasses.replace(arch.config, **model),
+        coding=dataclasses.replace(arch.coding, straggler_p=0.0))
+
+
 @pytest.fixture(scope="module")
 def flat_pad():
     spec = REGISTRY["xlstm-1.3b"]
-    cfg = spec.config.scaled(num_layers=8)
-    n = sum(l.size for l in jax.tree.leaves(Model(cfg).param_shapes()))
+    n = _n_params(spec.config.scaled(num_layers=8))
     pad = max(CocoEFConfig(compressor=c).pad_multiple
               for c in ("sign", "block_topk"))
     return padded_size(n, N_SENDERS, pad)
+
+
+def test_pad_multiple_pins_the_flat_layout():
+    """The kernels' grid tile is not the flat padding: every cell's flat
+    size stays a multiple of 8 groups of 512 (sign) and of lcm(that, 8
+    blocks of 256) (block top-K)."""
+    assert CocoEFConfig(compressor="sign").pad_multiple == 4096
+    assert CocoEFConfig(compressor="block_topk").pad_multiple == 4096
 
 
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _kernel_case(name, n, sh):
+def _kernel_case(name, n, sh, senders=N_SENDERS):
     """(fn, abstract args) of one train-path kernel at flat size n."""
     vec, scalar = _sds((n,), jnp.float32, sh), _sds((), jnp.float32, sh)
-    mask = _sds((N_SENDERS,), jnp.float32, sh)
-    chunk = n // N_SENDERS
+    mask = _sds((senders,), jnp.float32, sh)
+    chunk = n // senders
     if name == "ef_sign_fused":
         return (lambda g, e, a, m: sp.ef_sign_fused(
             g, e, a, m, GROUP, want_c=False, interpret=False),
@@ -77,8 +100,8 @@ def _kernel_case(name, n, sh):
     if name == "sign_decode_reduce":
         return (lambda w, s, m: sp.sign_decode_reduce(
             w, s, m, GROUP, interpret=False),
-            (_sds((N_SENDERS, chunk // 32), jnp.uint32, sh),
-             _sds((N_SENDERS, chunk // GROUP), jnp.float32, sh), mask))
+            (_sds((senders, chunk // 32), jnp.uint32, sh),
+             _sds((senders, chunk // GROUP), jnp.float32, sh), mask))
     if name == "ef_topk_fused":
         return (lambda g, e, a, m: tp.ef_topk_fused(
             g, e, a, m, K, BLOCK, want_c=False, interpret=False),
@@ -97,7 +120,22 @@ def _kernel_case(name, n, sh):
                                     "ef_topk_fused", "topk_decode_reduce",
                                     "topk_pack"])
 def test_train_path_kernel_compiles_for_v5e(kernel, one_chip, flat_pad):
+    if kernel in ("ef_sign_fused", "sign_decode_reduce"):
+        assert (flat_pad // N_SENDERS // GROUP) % sp.TILE_GROUPS, \
+            "the last grid step is not ragged"
     fn, args = _kernel_case(kernel, flat_pad, one_chip)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text, kernel
+    assert f"%{kernel}" in text, f"{kernel} is not the Mosaic call"
+
+
+@pytest.mark.parametrize("kernel", ["ef_sign_fused", "sign_decode_reduce"])
+def test_sign_kernels_compile_at_deepseek_size(kernel, one_chip):
+    spec = _deepseek_spec()
+    n = padded_size(_n_params(spec.config), 1,
+                    CocoEFConfig(compressor="sign").pad_multiple)
+    assert (n // GROUP) % sp.TILE_GROUPS, "the last grid step is not ragged"
+    fn, args = _kernel_case(kernel, n, one_chip, senders=1)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert 'custom_call_target="tpu_custom_call"' in text, kernel
     assert f"%{kernel}" in text, f"{kernel} is not the Mosaic call"
@@ -110,15 +148,10 @@ def test_deepseek_train_step_compiles_for_v5e(topo_devices, monkeypatch):
     from repro.kernels import ops
     from repro.launch.train import TrainRun, build_train_setup
     root = Path(__file__).resolve().parents[1]
-    model = json.loads((root / "bench/configs/deepseek-v2-lite-l5e8.json")
-                       .read_text())["model"]
     traffic = json.loads((root / "bench/traffic/sign.json").read_text())
     # code that asks for the default backend sees the CPU here
     monkeypatch.setattr(ops, "default_use_pallas", lambda: True)
-    arch = REGISTRY["deepseek-v2-lite-16b"]
-    spec = dataclasses.replace(
-        arch, config=dataclasses.replace(arch.config, **model),
-        coding=dataclasses.replace(arch.coding, straggler_p=0.0))
+    spec = _deepseek_spec()
     mesh = make_mesh((1, 1), ("data", "model"), devices=topo_devices[:1])
     plan = PlanSpec(d=1, compressor="sign", group_size=GROUP,
                     backend="pallas")
